@@ -9,8 +9,8 @@ refactor.  Because the refactored engine replays byte-identical
 *is* the event-throughput speedup.
 
 Also reports the §V benchmark-matrix wall time (the workload every figure
-module replays) and which dispatch path ``sched_many_fused`` takes on this
-backend.
+module replays).  All of it is host-CPU time: the engine never touches the
+device.
 
 Caches (shared VU programs / fluctuation bands) are cleared before each
 repeat so the numbers measure the engine, not warm caches; the baseline was
@@ -103,17 +103,6 @@ def run(quick: bool = False):
             "sim_speed/matrix_quick",
             matrix_wall / max(n_req, 1) * 1e6,
             f"wall_s={matrix_wall:.2f};requests={n_req}",
-        )
-    )
-    # which dispatch path the fused mixed-event API takes here
-    import jax
-
-    backend = jax.default_backend()
-    rows.append(
-        (
-            "sim_speed/fused_dispatch",
-            0.0,
-            f"backend={backend};path={'pallas_fused' if backend == 'tpu' else 'lax_scan_fallback'}",
         )
     )
     return rows

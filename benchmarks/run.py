@@ -28,6 +28,9 @@ def main(argv=None) -> None:
     )
     args = ap.parse_args(argv)
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_admission,
         bench_affinity,

@@ -20,8 +20,9 @@ Events are encoded as ``(kind, func, worker)`` int32 triples:
 Random tie-breaking uses the Gumbel-max trick over exact ties, matching the
 "random selection from W_min" of the fallback mechanism.
 
-``kernels/sched_step.py`` implements the ARRIVAL hot path as a fused Pallas
-kernel; ``kernels/ref.py`` points back at this module as the oracle.
+``kernels/sched_step.py`` fuses mixed event bursts into one Pallas kernel
+(``sched_many_fused``); ``kernels/ref.py`` points back at this module as the
+oracle.
 """
 
 from __future__ import annotations
@@ -32,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 ARRIVAL, FINISH, EVICT = 0, 1, 2
-_INF = jnp.int32(2**30)
+_INF = 2**30  # python int: a jnp scalar here would start a backend at import
 
 
 class JIQState(NamedTuple):
@@ -134,7 +135,7 @@ def sched_many_fused(
     events: jax.Array,
     key: jax.Array | None = None,
     chunk: int = 1024,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Tuple[JIQState, Tuple[jax.Array, jax.Array]]:
     """``sched_many`` with the whole stream fused into chunked Pallas dispatches.
 
@@ -143,18 +144,17 @@ def sched_many_fused(
     per event; state is carried between chunks.  Bit-exact against
     ``sched_many(state, events, key=None)``.
 
-    Fallback rules: with a PRNG ``key`` (randomized tie-breaks live in the
-    scan path) or off-TPU the scan path is used, keeping this a drop-in call
-    on any backend; ``interpret=True`` forces the fused kernel in interpreter
-    mode (CPU tests).
+    A PRNG ``key`` asks for randomized tie-breaks, which only the scan path
+    draws, so that call is ``sched_many``.  Otherwise the kernel runs
+    natively, which needs the TPU: on any other backend this raises unless
+    ``interpret=True`` runs the kernel body in Pallas's interpreter.
     """
     if key is not None:
         return sched_many(state, events, key)
-    if not interpret and jax.default_backend() != "tpu":
-        # off-TPU the native kernel can't lower; only interpret=True forces it
-        return sched_many(state, events, None)
-    from ..kernels import ops  # deferred: kernels are optional off the hot path
+    from ..kernels import ops  # deferred: ops imports models/ (via ref)
 
+    if not interpret:
+        ops.require_tpu("sched_many_fused")
     idle, conns = state.idle, state.conns
     n = events.shape[0]
     ws, warms = [], []
@@ -188,7 +188,7 @@ def sched_many_adaptive(
     densities=None,
     segment: int = 1024,
     key: jax.Array | None = None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Tuple[JIQState, Tuple[jax.Array, jax.Array]]:
     """Burst-adaptive fused dispatch: ``sched_many`` with per-window chunk
     sizes chosen by a :class:`~repro.core.simulator.BurstDetector`.

@@ -35,9 +35,7 @@ script.
 from __future__ import annotations
 
 import dataclasses
-import multiprocessing as mp
 import os
-import warnings
 from concurrent.futures import ProcessPoolExecutor
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -45,7 +43,7 @@ import numpy as np
 
 from .records import RecordColumns
 from .scheduler import make_scheduler
-from .shard import shard_seed
+from .shard import pool_context, shard_seed
 from .simulator import SimConfig, Simulator
 from .trace import VUProgram, make_functions
 
@@ -239,21 +237,9 @@ def _run_interleaved(scripts: Sequence[ShardScript]) -> List[ScriptResult]:
 
 
 def _run_process_pool(scripts: Sequence[ShardScript]) -> List[ScriptResult]:
-    # same fork-first idiom as core.shard: replay children are pure
-    # numpy/heapq and never enter XLA, so jax's blanket fork warning does
-    # not apply; REPRO_SHARD_START_METHOD overrides where fork is not viable
-    start = os.environ.get("REPRO_SHARD_START_METHOD") or (
-        "fork" if "fork" in mp.get_all_start_methods() else "spawn"
-    )
-    ctx = mp.get_context(start)
     max_workers = min(len(scripts), os.cpu_count() or 1)
-    with warnings.catch_warnings():
-        if start == "fork":
-            warnings.filterwarnings(
-                "ignore", message=r"os\.fork\(\) was called", category=RuntimeWarning
-            )
-        with ProcessPoolExecutor(max_workers=max_workers, mp_context=ctx) as pool:
-            return list(pool.map(run_script, scripts))
+    with ProcessPoolExecutor(max_workers=max_workers, mp_context=pool_context()) as pool:
+        return list(pool.map(run_script, scripts))
 
 
 def replay_shards(

@@ -56,8 +56,8 @@ from __future__ import annotations
 import dataclasses
 import multiprocessing as mp
 import os
+import sys
 import time
-import warnings
 from collections import deque
 from concurrent.futures import ProcessPoolExecutor
 from typing import Iterator, List, Optional, Sequence, Tuple
@@ -473,63 +473,64 @@ def _publish_chunks(chunks, bus, n_shards: int):
         yield ch
 
 
+def pool_context():
+    """Start method for the shard and replay process pools.
+
+    fork spares every child the interpreter start and the imports, and the
+    children are pure numpy/heapq and never enter XLA.  But a child forked
+    from a process whose JAX backend is live inherits that backend's
+    threads and, on a machine with a chip, its hold on the device; such a
+    parent spawns its children instead."""
+    xla_bridge = sys.modules.get("jax._src.xla_bridge")
+    jax_live = xla_bridge is not None and xla_bridge.backends_are_initialized()
+    if not jax_live and "fork" in mp.get_all_start_methods():
+        return mp.get_context("fork")
+    return mp.get_context("spawn")
+
+
 def _run_process_pool(
     specs: Sequence[ShardSpec], max_workers: Optional[int] = None
 ) -> List[ShardResult]:
-    # fork is the only start method that doesn't re-pay the jax import in
-    # every child; shard children are pure numpy/heapq and never enter XLA,
-    # so jax's blanket fork-deadlock warning doesn't apply — suppress just
-    # that warning at the fork site.  REPRO_SHARD_START_METHOD overrides
-    # (spawn/forkserver) for environments where fork is not viable.
-    start = os.environ.get("REPRO_SHARD_START_METHOD") or (
-        "fork" if "fork" in mp.get_all_start_methods() else "spawn"
-    )
-    ctx = mp.get_context(start)
     max_workers = max_workers or min(len(specs), os.cpu_count() or 1)
     use_shm = os.environ.get(TRANSPORT_ENV, "shm").strip().lower() != "pickle"
-    with warnings.catch_warnings():
-        if start == "fork":
-            warnings.filterwarnings(
-                "ignore", message=r"os\.fork\(\) was called", category=RuntimeWarning
-            )
+    ctx = pool_context()
+    if not use_shm:
         with ProcessPoolExecutor(max_workers=max_workers, mp_context=ctx) as pool:
-            if not use_shm:
-                return list(pool.map(run_shard, specs))
-            # parent names every segment up front: whatever happens in the
-            # children (including a crash mid-write), the finally below can
-            # find and unlink each one — deterministic teardown, no orphans
-            token = f"{SHM_PREFIX}{os.getpid()}-{os.urandom(4).hex()}"
-            named = [
-                dataclasses.replace(s, shm_name=f"{token}-{s.index}") for s in specs
-            ]
-            try:
-                shipments = list(pool.map(_run_shard_shipped, named))
-                results = []
-                for spec, ship in zip(specs, shipments):
-                    if ship.shm_name is None:  # zero-row shard: no segment
-                        cols = RecordColumns.empty()
-                        at = np.zeros(0, np.float64)
-                        aw = np.zeros(0, np.int64)
-                    else:
-                        cols, at, aw = read_columns_shm(
-                            ship.shm_name, ship.n_rec, ship.n_asg
-                        )
-                    results.append(
-                        ShardResult(
-                            spec=spec,  # the caller's spec: shm_name stays None
-                            records=cols,
-                            assign_t=at,
-                            assign_w=aw,
-                            n_events=ship.n_events,
-                            wall_s=ship.wall_s,
-                            resubmits=ship.resubmits,
-                            lost_tasks=ship.lost_tasks,
-                        )
-                    )
-                return results
-            finally:
-                for s in named:
-                    unlink_columns_shm(s.shm_name)
+            return list(pool.map(run_shard, specs))
+    # parent names every segment up front: whatever happens in the children
+    # (including a crash mid-write), the finally below can find and unlink
+    # each one — deterministic teardown, no orphans.  It runs only after the
+    # pool has joined its children, so a shard still running when another
+    # crashed cannot create its segment after the unlink.
+    token = f"{SHM_PREFIX}{os.getpid()}-{os.urandom(4).hex()}"
+    named = [dataclasses.replace(s, shm_name=f"{token}-{s.index}") for s in specs]
+    try:
+        with ProcessPoolExecutor(max_workers=max_workers, mp_context=ctx) as pool:
+            shipments = list(pool.map(_run_shard_shipped, named))
+        results = []
+        for spec, ship in zip(specs, shipments):
+            if ship.shm_name is None:  # zero-row shard: no segment
+                cols = RecordColumns.empty()
+                at = np.zeros(0, np.float64)
+                aw = np.zeros(0, np.int64)
+            else:
+                cols, at, aw = read_columns_shm(ship.shm_name, ship.n_rec, ship.n_asg)
+            results.append(
+                ShardResult(
+                    spec=spec,  # the caller's spec: shm_name stays None
+                    records=cols,
+                    assign_t=at,
+                    assign_w=aw,
+                    n_events=ship.n_events,
+                    wall_s=ship.wall_s,
+                    resubmits=ship.resubmits,
+                    lost_tasks=ship.lost_tasks,
+                )
+            )
+        return results
+    finally:
+        for s in named:
+            unlink_columns_shm(s.shm_name)
 
 
 def _run_interleaved(

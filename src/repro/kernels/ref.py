@@ -5,8 +5,9 @@ Each function mirrors the exact contract of its kernel in ops.py:
   decode_attention_ref <-> kernels/decode_attention.py
   ssd_scan_ref         <-> kernels/ssd_scan.py  (the chunked SSD of
                            models/mamba.py, re-exported for the sweep tests)
-  sched_step_ref       <-> kernels/sched_step.py (vectorized Algorithm 1
-                           ARRIVAL path over a request burst)
+  sched_step_ref       <-> kernels/sched_step.py on ARRIVAL-only bursts
+                           (vectorized Algorithm 1 over a request burst)
+  sched_events_ref     <-> kernels/sched_step.py (mixed event bursts)
 """
 
 from __future__ import annotations

@@ -1,30 +1,28 @@
-"""Fused pull-based scheduling in Pallas: ARRIVAL bursts + mixed events.
+"""Fused pull-based scheduling in Pallas: mixed ARRIVAL/FINISH/EVICT bursts.
 
 The paper's own hot path: for each request in a burst, (1) masked-argmin over
 workers with an idle instance of the requested function (the PQ_f dequeue),
 (2) least-connections argmin fallback, (3) connection/idle-table updates that
-the *next* request in the burst observes.  The sequential dependence makes
+the *next* event in the burst observes.  The sequential dependence makes
 this a scan — fused here into one kernel invocation so the whole burst costs
 one dispatch (vs. one XLA scan iteration each; see benchmarks/bench_kernels).
 
-Two kernels:
-
-* ``sched_step``   — the original ARRIVAL-only burst.
-* ``sched_events`` — full mixed ``(ARRIVAL|FINISH|EVICT)`` event streams, the
-  fused form of ``core.jax_sched.sched_step`` scanned over a burst: FINISH
-  performs the pull enqueue (idle[f, w] += 1, connection closes), EVICT the
-  notification removal.  Bit-exact against ``sched_many(..., key=None)``
-  (deterministic lowest-index ties); exposed as
-  ``core.jax_sched.sched_many_fused`` with chunking + off-TPU fallback.
+``sched_events`` runs full mixed ``(ARRIVAL|FINISH|EVICT)`` event streams,
+the fused form of ``core.jax_sched.sched_step`` scanned over a burst: FINISH
+performs the pull enqueue (idle[f, w] += 1, connection closes), EVICT the
+notification removal.  Bit-exact against ``sched_many(..., key=None)``
+(deterministic lowest-index ties); exposed as
+``core.jax_sched.sched_many_fused`` with chunking.  An ARRIVAL-only burst is
+the vectorized Algorithm 1 of ``ref.sched_step_ref``.
 
 Layout: workers live on the 128-lane axis (W padded to a lane multiple by
-ops.py, padding masked with +INF connections); the idle table rows for the
-burst's functions are resident in VMEM; the request loop is a fori_loop with
-dynamic row loads — the TPU analogue of the paper's Go scheduler loop.
-
-Tie-breaking is deterministic (lowest index), matching ``ref.sched_step_ref``;
-the randomized tie-break of Algorithm 1 lives in the control plane
-(core/jax_sched.py) where a PRNG key is available.
+ops.py, padding masked with +INF connections); the whole idle table and the
+``(1, W)`` connection row are resident in VMEM; the event loop is a
+fori_loop that loads the event's function row with a dynamic *sublane*
+index.  Every update is a whole-row ``where(lane == w, ...)`` written back as
+a row: the TPU compiler rejects single-lane dynamic slices on the lane axis,
+and its argmin handles float32 only, so the lowest-index argmin is a row min
+followed by the min lane index attaining it.
 """
 
 from __future__ import annotations
@@ -37,67 +35,22 @@ from jax.experimental.pallas import tpu as pltpu
 _INF = 2**30  # python int: jnp scalars would be captured as kernel constants
 
 
-def _sched_kernel(funcs_ref, idle_ref, conns_ref, assign_ref, warm_ref, idle_out, conns_out):
-    idle_out[...] = idle_ref[...]
-    conns_out[...] = conns_ref[...]
-    R = funcs_ref.shape[0]
-    W = conns_ref.shape[0]
-
-    def body(i, _):
-        f = funcs_ref[i]
-        row = pl.load(idle_out, (pl.dslice(f, 1), slice(None)))[0]  # (W,)
-        conns = conns_out[...]
-        has_idle = jnp.any(row > 0)
-        pull_scores = jnp.where(row > 0, conns, _INF)
-        w_pull = jnp.argmin(pull_scores).astype(jnp.int32)
-        w_fb = jnp.argmin(conns).astype(jnp.int32)
-        w = jnp.where(has_idle, w_pull, w_fb)
-        # dequeue from PQ_f (if pulled) + open connection
-        dec = has_idle.astype(jnp.int32)
-        old_row = pl.load(idle_out, (pl.dslice(f, 1), pl.dslice(w, 1)))
-        pl.store(idle_out, (pl.dslice(f, 1), pl.dslice(w, 1)), old_row - dec)
-        old_c = pl.load(conns_out, (pl.dslice(w, 1),))
-        pl.store(conns_out, (pl.dslice(w, 1),), old_c + 1)
-        pl.store(assign_ref, (pl.dslice(i, 1),), w[None])
-        pl.store(warm_ref, (pl.dslice(i, 1),), has_idle[None].astype(jnp.int32))
-        return 0
-
-    jax.lax.fori_loop(0, R, body, 0)
+def vmem_bytes(n_funcs: int, n_workers: int) -> int:
+    """Scoped VMEM the kernel asks for at ``(F, W)``: the idle table
+    (``(8, 128)`` int32 tiles) and the connection row, each in and out, plus
+    24 ``(1, W)`` rows for the event loop's temporaries (the v5e compiler
+    takes up to ~19 when the limit allows) and 1 MiB."""
+    lanes = -(-n_workers // 128) * 128
+    sublanes = -(-n_funcs // 8) * 8
+    return 4 * lanes * (2 * (sublanes + 1) + 24) + 2**20
 
 
-def sched_step(
-    funcs: jax.Array,  # (R,) int32
-    idle: jax.Array,   # (F, W) int32
-    conns: jax.Array,  # (W,) int32
-    interpret: bool = False,
-):
-    """Returns (assign (R,), warm (R,) int32, idle', conns')."""
-    R = funcs.shape[0]
-    F, W = idle.shape
-    return pl.pallas_call(
-        _sched_kernel,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((F, W), lambda: (0, 0)),
-            pl.BlockSpec((W,), lambda: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((F, W), lambda: (0, 0)),
-            pl.BlockSpec((W,), lambda: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R,), jnp.int32),
-            jax.ShapeDtypeStruct((R,), jnp.int32),
-            jax.ShapeDtypeStruct((F, W), jnp.int32),
-            jax.ShapeDtypeStruct((W,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(funcs, idle, conns)
+def _first_argmin(scores, lane):
+    """Lowest lane index attaining the row minimum (``jnp.argmin``'s tie)."""
+    m = jnp.min(scores)
+    return jnp.min(jnp.where(scores == m, lane, _INF))
 
 
-# --------------------------------------------------------------- mixed events
 def _sched_events_kernel(
     kinds_ref, funcs_ref, workers_ref, idle_ref, conns_ref,
     assign_ref, warm_ref, idle_out, conns_out,
@@ -105,86 +58,74 @@ def _sched_events_kernel(
     idle_out[...] = idle_ref[...]
     conns_out[...] = conns_ref[...]
     R = kinds_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, conns_ref.shape, 1)  # (1, W)
 
     def body(i, _):
         k = kinds_ref[i]
         f = funcs_ref[i]
-        w_ev = workers_ref[i]
-        w_ev = jnp.where(w_ev < 0, 0, w_ev)  # ARRIVAL carries -1: unused below
+        w_ev = workers_ref[i]  # ARRIVAL carries -1: matches no lane
         is_arr = (k == 0).astype(jnp.int32)
         is_fin = (k == 1).astype(jnp.int32)
         is_evt = (k == 2).astype(jnp.int32)
 
-        row = pl.load(idle_out, (pl.dslice(f, 1), slice(None)))[0]  # (W,)
+        row = idle_out[pl.ds(f, 1), :]  # (1, W)
         conns = conns_out[...]
-        has_idle = jnp.any(row > 0)
-        pull_scores = jnp.where(row > 0, conns, _INF)
-        w_pull = jnp.argmin(pull_scores).astype(jnp.int32)
-        w_fb = jnp.argmin(conns).astype(jnp.int32)
+        has_idle = jnp.max(row) > 0
+        w_pull = _first_argmin(jnp.where(row > 0, conns, _INF), lane)
+        w_fb = _first_argmin(conns, lane)
         w_assign = jnp.where(has_idle, w_pull, w_fb)
 
         # ARRIVAL: dequeue from PQ_f (if pulled) + open connection
         dec = is_arr * has_idle.astype(jnp.int32)
-        cell = pl.load(idle_out, (pl.dslice(f, 1), pl.dslice(w_assign, 1)))
-        pl.store(idle_out, (pl.dslice(f, 1), pl.dslice(w_assign, 1)), cell - dec)
-        c_cell = pl.load(conns_out, (pl.dslice(w_assign, 1),))
-        pl.store(conns_out, (pl.dslice(w_assign, 1),), c_cell + is_arr)
+        at_assign = lane == w_assign
+        row = row - jnp.where(at_assign, dec, 0)
+        conns = conns + jnp.where(at_assign, is_arr, 0)
 
         # FINISH: pull enqueue + close connection; EVICT: notification removal
-        cell = pl.load(idle_out, (pl.dslice(f, 1), pl.dslice(w_ev, 1)))
-        cell = cell + is_fin
-        cell = cell - is_evt * (cell > 0).astype(jnp.int32)
-        pl.store(idle_out, (pl.dslice(f, 1), pl.dslice(w_ev, 1)), cell)
-        c_cell = pl.load(conns_out, (pl.dslice(w_ev, 1),))
-        c_cell = c_cell - is_fin
-        c_cell = jnp.maximum(c_cell, 0)
-        pl.store(conns_out, (pl.dslice(w_ev, 1),), c_cell)
+        at_ev = lane == w_ev
+        row = row + jnp.where(at_ev, is_fin, 0)
+        row = row - jnp.where(at_ev & (row > 0), is_evt, 0)
+        conns = jnp.where(at_ev, jnp.maximum(conns - is_fin, 0), conns)
 
-        pl.store(assign_ref, (pl.dslice(i, 1),),
-                 jnp.where(is_arr == 1, w_assign, jnp.int32(-1))[None])
-        pl.store(warm_ref, (pl.dslice(i, 1),),
-                 (is_arr * has_idle.astype(jnp.int32))[None])
+        idle_out[pl.ds(f, 1), :] = row
+        conns_out[...] = conns
+        assign_ref[i] = jnp.where(is_arr == 1, w_assign, -1)
+        warm_ref[i] = dec
         return 0
 
     jax.lax.fori_loop(0, R, body, 0)
 
 
 def sched_events(
-    kinds: jax.Array,   # (R,) int32 — 0 ARRIVAL / 1 FINISH / 2 EVICT
-    funcs: jax.Array,   # (R,) int32
+    kinds: jax.Array,    # (R,) int32 — 0 ARRIVAL / 1 FINISH / 2 EVICT
+    funcs: jax.Array,    # (R,) int32
     workers: jax.Array,  # (R,) int32 (-1 for ARRIVAL)
-    idle: jax.Array,    # (F, W) int32
-    conns: jax.Array,   # (W,) int32
+    idle: jax.Array,     # (F, W) int32
+    conns: jax.Array,    # (1, W) int32
     interpret: bool = False,
 ):
     """Fused mixed-event burst.  Returns (assign, warm, idle', conns').
 
     One dispatch per burst; semantics identical to scanning
     ``core.jax_sched.sched_step`` with ``key=None`` (assign/warm are -1/0 for
-    non-ARRIVAL events).
+    non-ARRIVAL events; any other kind is a no-op).  Scoped VMEM is sized
+    by :func:`vmem_bytes`.
     """
     R = kinds.shape[0]
     F, W = idle.shape
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    table = pl.BlockSpec((F, W), lambda: (0, 0))
+    row = pl.BlockSpec((1, W), lambda: (0, 0))
     return pl.pallas_call(
         _sched_events_kernel,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((F, W), lambda: (0, 0)),
-            pl.BlockSpec((W,), lambda: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((F, W), lambda: (0, 0)),
-            pl.BlockSpec((W,), lambda: (0,)),
-        ],
+        in_specs=[smem, smem, smem, table, row],
+        out_specs=[smem, smem, table, row],
         out_shape=[
             jax.ShapeDtypeStruct((R,), jnp.int32),
             jax.ShapeDtypeStruct((R,), jnp.int32),
             jax.ShapeDtypeStruct((F, W), jnp.int32),
-            jax.ShapeDtypeStruct((W,), jnp.int32),
+            jax.ShapeDtypeStruct((1, W), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes(F, W)),
         interpret=interpret,
     )(kinds, funcs, workers, idle, conns)

@@ -21,6 +21,7 @@ from repro.configs import get_config
 from repro.core.scheduler import available_schedulers
 from repro.core.trace import azure_like_weights
 from repro.serving import Endpoint, ServingEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _endpoint(name, seed):
@@ -41,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     eps = [_endpoint(f"fn-{i}", i) for i in range(args.endpoints)]
     eng = ServingEngine(eps, n_workers=args.workers, scheduler=args.scheduler,
                         seed=args.seed)

@@ -21,11 +21,13 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .layers import Param, apply_rope, param, rmsnorm
 
 _NEG_INF = -2.0e38
-GLOBAL_WINDOW = jnp.int32(2**30)  # "window" value meaning full attention
+# "window" value meaning full attention (numpy: no backend starts at import)
+GLOBAL_WINDOW = np.int32(2**30)
 
 
 # ------------------------------------------------------------------- params

@@ -14,6 +14,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -26,8 +27,14 @@ class RequestResult:
     func: str
     worker: int
     cold: bool
-    latency_ms: float
+    init_ms: float  # cold start: instance materialization (0 when warm)
+    exec_ms: float
     sched_overhead_ms: float
+    tokens: jax.Array  # (B, gen_len) generated token ids
+
+    @property
+    def latency_ms(self) -> float:
+        return self.init_ms + self.exec_ms
 
 
 class ServingEngine:
@@ -63,8 +70,8 @@ class ServingEngine:
         rec: ExecutionRecord = self.workers[w].execute(ep, tokens, gen_len)
         self.sched.on_finish(w, func)
         out = RequestResult(
-            func=func, worker=w, cold=rec.cold,
-            latency_ms=rec.total_ms, sched_overhead_ms=t_sched,
+            func=func, worker=w, cold=rec.cold, init_ms=rec.init_ms,
+            exec_ms=rec.exec_ms, sched_overhead_ms=t_sched, tokens=rec.tokens,
         )
         self.records.append(out)
         return out

@@ -80,10 +80,7 @@ class ExecutionRecord:
     cold: bool
     init_ms: float
     exec_ms: float
-
-    @property
-    def total_ms(self) -> float:
-        return self.init_ms + self.exec_ms
+    tokens: jax.Array  # (B, gen_len) generated token ids
 
 
 class WorkerHost:
@@ -141,7 +138,7 @@ class WorkerHost:
         else:
             inst = self.idle[ep.name].pop()
         t1 = time.perf_counter()
-        inst.generate(tokens, gen_len)
+        out = inst.generate(tokens, gen_len)
         t2 = time.perf_counter()
         inst.last_used = time.monotonic()
         self.idle.setdefault(ep.name, []).append(inst)
@@ -149,4 +146,5 @@ class WorkerHost:
             func=ep.name, worker=self.wid, cold=cold,
             init_ms=(t1 - t0) * 1e3 if cold else 0.0,
             exec_ms=(t2 - t1) * 1e3,
+            tokens=out,
         )

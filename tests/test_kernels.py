@@ -1,5 +1,5 @@
 """Per-kernel validation: shape/dtype sweeps vs the pure-jnp oracles
-(interpret mode executes the kernel bodies on CPU).  Hypothesis property
+(``interpret=True`` executes the kernel bodies on CPU).  Hypothesis property
 tests live in test_kernels_properties.py so this module runs even where
 hypothesis isn't installed."""
 
@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, sched_step
 
 jax.config.update("jax_enable_x64", False)
 
@@ -33,7 +33,8 @@ def test_flash_attention_sweep(B, S, H, KH, hd, causal, window, dtype):
     q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
     k = jax.random.normal(ks[1], (B, S, KH, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, KH, hd), dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, window=window, block_q=64, block_k=64)
+    out = ops.flash_attention(q, k, v, causal=causal, window=window, block_q=64, block_k=64,
+                              interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -56,7 +57,8 @@ def test_decode_attention_sweep(B, S, H, KH, hd, valid, window, dtype):
     q = jax.random.normal(ks[0], (B, H, hd), dtype)
     kc = jax.random.normal(ks[1], (B, S, KH, hd), dtype)
     vc = jax.random.normal(ks[2], (B, S, KH, hd), dtype)
-    out = ops.decode_attention(q, kc, vc, jnp.int32(valid), window=window, block_k=128)
+    out = ops.decode_attention(q, kc, vc, jnp.int32(valid), window=window, block_k=128,
+                               interpret=True)
     want = ref.decode_attention_ref(q, kc, vc, jnp.int32(valid), window=window)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -71,7 +73,8 @@ def test_decode_attention_matches_flash_last_row():
     k = jax.random.normal(ks[1], (B, S, KH, hd))
     v = jax.random.normal(ks[2], (B, S, KH, hd))
     full = ref.flash_attention_ref(q, k, v, causal=True)
-    dec = ops.decode_attention(q[:, -1], k, v, jnp.int32(S - 1), block_k=64)
+    dec = ops.decode_attention(q[:, -1], k, v, jnp.int32(S - 1), block_k=64,
+                               interpret=True)
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full[:, -1]), atol=2e-5, rtol=2e-5)
 
 
@@ -93,7 +96,8 @@ def test_ssd_scan_sweep(B, S, H, P, N, chunk, block_h, dtype):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.3)
     Bm = (jax.random.normal(ks[3], (B, S, 1, N)) * 0.3).astype(dtype)
     Cm = (jax.random.normal(ks[4], (B, S, 1, N)) * 0.3).astype(dtype)
-    y, st = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, block_h=block_h)
+    y, st = ops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk, block_h=block_h,
+                         interpret=True)
     yr, sr = ref.ssd_scan_ref(
         x.astype(jnp.float32), dt, A, Bm.astype(jnp.float32), Cm.astype(jnp.float32), chunk
     )
@@ -120,45 +124,49 @@ def test_ssd_scan_state_carry_equals_two_halves():
 
 
 # ------------------------------------------------- fused mixed-event dispatch
-@pytest.mark.parametrize("R,F,W", [(32, 4, 8), (100, 10, 16), (57, 3, 5), (128, 40, 130)])
-def test_sched_events_sweep(R, F, W):
-    """Fused (ARRIVAL|FINISH|EVICT) kernel == the jax_sched scan oracle."""
+@pytest.mark.parametrize(
+    "R,F,W,arrivals_only",
+    [
+        (32, 4, 8, False), (100, 10, 16, False), (57, 3, 5, False),
+        (128, 40, 130, False),
+        # ARRIVAL-only bursts: Algorithm 1's vectorized burst
+        (16, 4, 8, True), (64, 10, 16, True), (8, 1, 4, True),
+        (128, 40, 5, True), (48, 6, 8, True),
+    ],
+)
+def test_sched_events_sweep(R, F, W, arrivals_only):
+    """Fused (ARRIVAL|FINISH|EVICT) kernel == the jax_sched scan oracle, and
+    on ARRIVAL-only bursts == ``ref.sched_step_ref`` as well."""
     rng = np.random.default_rng(R * 1000 + W)
-    kinds = rng.integers(0, 3, R)
+    kinds = np.zeros(R, np.int32) if arrivals_only else rng.integers(0, 3, R)
     funcs = rng.integers(0, F, R)
     workers = np.where(kinds == 0, -1, rng.integers(0, W, R))
     idle = rng.integers(0, 3, (F, W))
     conns = rng.integers(0, 5, W)
     args = [jnp.asarray(a, jnp.int32) for a in (kinds, funcs, workers, idle, conns)]
-    a, warm, i2, c2 = ops.sched_events(*args)
+    a, warm, i2, c2 = ops.sched_events(*args, interpret=True)
     ar, wr, ir, cr = ref.sched_events_ref(*args)
     assert jnp.all(a == ar) and jnp.all(warm == wr)
     assert jnp.all(i2 == ir) and jnp.all(c2 == cr)
+    if arrivals_only:
+        ar, wr, ir, cr = ref.sched_step_ref(args[1], args[3], args[4])
+        assert jnp.all(a == ar) and jnp.all(warm == wr.astype(jnp.int32))
+        assert jnp.all(i2 == ir) and jnp.all(c2 == cr)
 
 
-def test_sched_events_arrival_only_matches_sched_step():
-    """On a pure ARRIVAL burst the mixed kernel degenerates to sched_step."""
-    ks = jax.random.split(jax.random.key(9), 3)
-    R, F, W = 48, 6, 8
-    funcs = jax.random.randint(ks[0], (R,), 0, F)
-    idle = jax.random.randint(ks[1], (F, W), 0, 3)
-    conns = jax.random.randint(ks[2], (W,), 0, 5)
-    kinds = jnp.zeros((R,), jnp.int32)
-    workers = jnp.full((R,), -1, jnp.int32)
-    a1, w1, i1, c1 = ops.sched_events(kinds, funcs, workers, idle, conns)
-    a2, w2, i2, c2 = ops.sched_step(funcs, idle, conns)
-    assert jnp.all(a1 == a2) and jnp.all(w1 == w2)
-    assert jnp.all(i1 == i2) and jnp.all(c1 == c2)
-
-
-# ------------------------------------------------------------- scheduler step
-@pytest.mark.parametrize("R,F,W", [(16, 4, 8), (64, 10, 16), (8, 1, 4), (128, 40, 5)])
-def test_sched_step_sweep(R, F, W):
-    ks = jax.random.split(jax.random.key(5), 3)
-    funcs = jax.random.randint(ks[0], (R,), 0, F)
-    idle = jax.random.randint(ks[1], (F, W), 0, 3)
-    conns = jax.random.randint(ks[2], (W,), 0, 5)
-    a, warm, i2, c2 = ops.sched_step(funcs, idle, conns)
-    ar, wr, ir, cr = ref.sched_step_ref(funcs, idle, conns)
-    assert jnp.all(a == ar) and jnp.all(warm == wr.astype(jnp.int32))
-    assert jnp.all(i2 == ir) and jnp.all(c2 == cr)
+def test_sched_events_native_guards(monkeypatch):
+    """The native kernel never runs elsewhere: off the TPU it raises naming
+    the backend, and a table wider than the VMEM cap raises naming the
+    width instead of running another path."""
+    shapes = [jax.ShapeDtypeStruct(s, jnp.int32) for s in [(8,), (8,), (8,)]]
+    with pytest.raises(RuntimeError, match="'cpu'"):
+        ops.sched_events(*shapes, jnp.zeros((4, 8), jnp.int32),
+                         jnp.zeros((8,), jnp.int32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    F = 40
+    W = next(w for w in range(128, 10**6, 128)
+             if sched_step.vmem_bytes(F, w) > ops.SCHED_VMEM_CAP_BYTES)
+    assert sched_step.vmem_bytes(F, W - 128) <= ops.SCHED_VMEM_CAP_BYTES
+    with pytest.raises(ValueError, match=f"{W} workers"):
+        ops.sched_events(*shapes, jax.ShapeDtypeStruct((F, W), jnp.int32),
+                         jax.ShapeDtypeStruct((W,), jnp.int32))

@@ -154,8 +154,9 @@ def test_writer_crash_before_merge_leaves_no_orphans(monkeypatch):
     from repro.core import shard as shard_mod
 
     monkeypatch.setattr(shard_mod, "_run_shard_shipped", _crash_after_write)
-    before = _segments()
+    own = f"{SHM_PREFIX}{os.getpid()}-"  # this driver's names, not other pools'
+    before = {n for n in _segments() if n.startswith(own)}
     driver = ShardedSimulator(2, 4, scheduler="hiku", seed=0, backend="process")
     with pytest.raises(RuntimeError, match="writer crashed"):
         driver.run(n_vus=4, duration_s=2.0)
-    assert _segments() - before == set()
+    assert {n for n in _segments() if n.startswith(own)} - before == set()

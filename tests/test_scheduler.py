@@ -135,9 +135,9 @@ def test_sched_many_fused_matches_scan():
     s2, (ws2, warm2) = sched_many_fused(state, ev, chunk=64, interpret=True)
     assert jnp.all(ws1 == ws2) and jnp.all(warm1 == warm2)
     assert jnp.all(s1.idle == s2.idle) and jnp.all(s1.conns == s2.conns)
-    # off-TPU default silently falls back to the scan path
-    s3, (ws3, _) = sched_many_fused(state, ev)
-    assert jnp.all(ws1 == ws3) and jnp.all(s1.conns == s3.conns)
+    # off the TPU the native kernel raises, naming the backend it found
+    with pytest.raises(RuntimeError, match=repr(jax.default_backend())):
+        sched_many_fused(state, ev)
 
 
 def _mixed_events(rng, n, n_funcs=6, n_workers=9):

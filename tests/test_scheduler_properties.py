@@ -15,10 +15,17 @@ from repro.core import ARRIVAL, FINISH, HikuScheduler, init_state, sched_many  #
 
 
 class _FirstChoice:
-    """Deterministic stand-in for random.Random: always pick first/lowest."""
+    """Deterministic stand-in for random.Random: always pick first/lowest.
+
+    The least-connections fallback draws ``randrange(t)`` over its ``t``
+    tied workers in ascending id order, so 0 is the lowest tied id — the
+    tie the JAX scan breaks."""
 
     def choice(self, xs):
         return min(xs)
+
+    def randrange(self, n):
+        return 0
 
 
 @settings(max_examples=25, deadline=None)

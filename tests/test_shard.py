@@ -65,6 +65,44 @@ def test_backends_identical_to_serial(backend):
     assert np.array_equal(base.assign_w, other.assign_w)
 
 
+def test_pool_never_forks_a_live_jax_backend():
+    """Importing the library starts no JAX backend, and a parent that never
+    started one forks its pool children; a parent whose backend is live
+    spawns them (a forked child would inherit the backend's threads and the
+    device), with the same results."""
+    import os
+    import subprocess
+    import sys
+
+    import jax
+
+    import repro
+    from repro.core.shard import pool_context
+
+    src = os.path.dirname(os.path.dirname(repro.__file__))
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "import repro.kernels.ops, repro.serving; "
+         "from repro.core.shard import pool_context; "
+         "print(pool_context().get_start_method())"],
+        capture_output=True, text=True, timeout=120, check=True,
+        env={**os.environ, "PYTHONPATH": src},
+    )
+    assert probe.stdout.strip() == "fork"
+    jax.devices()  # the backend is live from here on
+    assert pool_context().get_start_method() == "spawn"
+
+    def run(b):
+        return ShardedSimulator(2, 6, scheduler="hiku", seed=3, backend=b).run(
+            n_vus=10, duration_s=8.0
+        )
+
+    spawned, serial = run("process"), run("serial")
+    assert len(serial.records) > 0
+    assert spawned.records.equals(serial.records)
+    assert np.array_equal(spawned.assign_w, serial.assign_w)
+
+
 def test_process_transport_fallback_matches_shm(monkeypatch):
     """``REPRO_SHARD_TRANSPORT=pickle`` ships the same bytes the shared-
     memory segments do — the transport is invisible to every consumer."""
@@ -97,9 +135,12 @@ def test_process_backend_teardown_is_deterministic():
     from repro.core.shard import SHM_PREFIX
 
     def segments():
+        # the driver names its segments after its own pid; other test
+        # processes' pools come and go in /dev/shm meanwhile
         if not os.path.isdir("/dev/shm"):
             return set()
-        return {f for f in os.listdir("/dev/shm") if f.startswith(SHM_PREFIX)}
+        own = f"{SHM_PREFIX}{os.getpid()}-"
+        return {f for f in os.listdir("/dev/shm") if f.startswith(own)}
 
     before = segments()
     for seed in (1, 2):

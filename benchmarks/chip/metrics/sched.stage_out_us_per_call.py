@@ -1,0 +1,13 @@
+"""Device-idle time per call in the wrapper's stage-out, in us: for each
+``sched.fused`` span of the traced window (one ``sched_many_fused`` call),
+the summed length of its ``sched.stage_out`` spans (the unpad slices of
+each chunk, and the final concatenation of the answers) less the
+device-busy time inside them; the mean over the calls.  None where the
+program writes no such span (``program_spans.py``).  Moves
+``decision_ms_p95``."""
+
+from program_spans import stage_idle_us_per_call
+
+
+def read(ctx):
+    return stage_idle_us_per_call(ctx, "sched.stage_out")

@@ -44,39 +44,15 @@ from repro.core import HikuScheduler, JIQState, sched_many, sched_many_fused  # 
 from repro.core.jax_sched import ARRIVAL, EVICT, FINISH  # noqa: E402
 from repro.core.trace import make_functions  # noqa: E402
 from repro.serving import Endpoint, Instance, ServingEngine  # noqa: E402
-from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
+from repro.utils.compile_cache import compile_count, enable_compile_cache  # noqa: E402
 
 CHUNK = 1024
 N_WORKERS, N_CHUNKS, WIDE_WORKERS = 1600, 64, 100_000
-TIMING_REPS = 3
 GEN_LEN = 4
 # a served token may trail the CPU reference's best logit by this many
 # standard deviations of the logits (matmul precision differs between the
 # chip and the CPU); a token picked from wrong numbers lies about 4.5 below
 MAX_GAP_STD = 0.1
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-class CompileMeter:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events (a cache hit is counted as its retrieval time)."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == _COMPILE_EVENT:
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def reading(self):
-        return {"compile_s": self.seconds, "cache_hits": self.hits}
 
 
 class _LowestTie:
@@ -167,9 +143,8 @@ def _equal(name, got, want):
 
 
 def check_dispatch(n_workers, n_events, seed, n_prefix=0, interpret=False):
-    """One dispatch check; returns the start state and the events.
-    ``interpret=True`` runs the kernel in Pallas's interpreter (off the
-    chip, at test widths)."""
+    """One dispatch check.  ``interpret=True`` runs the kernel in Pallas's
+    interpreter (off the chip, at test widths)."""
     (idle0, conns0), ev, assign, warm, (idle1, conns1) = make_stream(
         n_workers, n_events, seed, n_prefix
     )
@@ -195,36 +170,10 @@ def check_dispatch(n_workers, n_events, seed, n_prefix=0, interpret=False):
         "fused == scan == host",
         flush=True,
     )
-    return state, events
-
-
-def time_chunks(state, events):
-    """Host-clock seconds per 1,024-event chunk after warm-up, fused vs
-    scan, each the mean of ``TIMING_REPS`` calls ended by
-    ``block_until_ready``."""
-    n_chunks = events.shape[0] // CHUNK
-    out = {}
-    for name, fn in {
-        "fused": lambda: sched_many_fused(state, events, chunk=CHUNK),
-        "scan": lambda: sched_many(state, events),
-    }.items():
-        jax.block_until_ready(fn())
-        t0 = time.perf_counter()
-        for _ in range(TIMING_REPS):
-            jax.block_until_ready(fn())
-        out[name] = (time.perf_counter() - t0) / TIMING_REPS / n_chunks
-    return out
 
 
 def dispatch_phase(seed):
-    state, events = check_dispatch(N_WORKERS, N_CHUNKS * CHUNK, seed)
-    per_chunk = time_chunks(state, events)
-    print(
-        f"dispatch W={N_WORKERS}: host-clock per {CHUNK}-event chunk after "
-        f"warm-up: fused {per_chunk['fused'] * 1e3} ms, "
-        f"scan {per_chunk['scan'] * 1e3} ms",
-        flush=True,
-    )
+    check_dispatch(N_WORKERS, N_CHUNKS * CHUNK, seed)
     check_dispatch(WIDE_WORKERS, CHUNK, seed + 1, n_prefix=20_000)
 
 
@@ -313,14 +262,16 @@ def main(argv=None):
               file=sys.stderr)
         return 2
     cache_dir = enable_compile_cache()
-    meter = CompileMeter()
+    count = compile_count()
     print(f"device {dev.platform} {dev.device_kind} x{len(jax.devices())}; "
           f"compile cache {cache_dir}", flush=True)
     for name, phase in (("dispatch", dispatch_phase), ("served", served_phase)):
         t0 = time.perf_counter()
         phase(args.seed)
         print(f"phase {name}: wall_s={time.perf_counter() - t0} "
-              f"{meter.reading()}", flush=True)
+              f"compiles={count.compiles} compile_s={count.seconds} "
+              f"cache_hits={count.cache_hits}",
+              flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

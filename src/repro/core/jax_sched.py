@@ -31,6 +31,9 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
+
+from ..utils.compile_cache import compile_count
 
 ARRIVAL, FINISH, EVICT = 0, 1, 2
 _INF = 2**30  # python int: a jnp scalar here would start a backend at import
@@ -148,36 +151,60 @@ def sched_many_fused(
     draws, so that call is ``sched_many``.  Otherwise the kernel runs
     natively, which needs the TPU: on any other backend this raises unless
     ``interpret=True`` runs the kernel body in Pallas's interpreter.
+
+    The kernel path writes profiler spans (``jax.profiler.TraceAnnotation``;
+    they cost about a microsecond each when no trace is active):
+    ``sched.fused`` around the whole call, with the metadata ``chunks``,
+    ``events`` (real events handed in), ``padded`` (no-op events added to
+    fill the last chunk) and ``compiles`` (backend compiles during the call,
+    :func:`repro.utils.compile_cache.compile_count`); and per chunk
+    ``sched.stage_in`` (slice, pad and move the events to the device, split
+    the columns), ``sched.launch`` (``ops.sched_events``) and
+    ``sched.stage_out`` (unpad), with one more ``sched.stage_out`` around
+    the final concatenation of the answers.
     """
     if key is not None:
         return sched_many(state, events, key)
     from ..kernels import ops  # deferred: ops imports models/ (via ref)
 
-    if not interpret:
-        ops.require_tpu("sched_many_fused")
-    idle, conns = state.idle, state.conns
-    n = events.shape[0]
-    ws, warms = [], []
-    for lo in range(0, n, chunk):
-        ev = events[lo : lo + chunk]
-        tail = chunk - ev.shape[0]
-        if tail:
-            # pad the ragged last chunk with kind=3 no-op events (func/worker
-            # 0 keep the row loads in bounds; an unknown kind updates nothing)
-            # so every dispatch shares one compiled (chunk,) shape
-            pad = jnp.zeros((tail, 3), jnp.int32).at[:, 0].set(3)
-            ev = jnp.concatenate([ev, pad])
-        a, warm, idle, conns = ops.sched_events(
-            ev[:, 0], ev[:, 1], ev[:, 2], idle, conns, interpret=interpret
-        )
-        if tail:
-            a, warm = a[:-tail], warm[:-tail]
-        ws.append(a)
-        warms.append(warm)
-    ws_all = jnp.concatenate(ws) if ws else jnp.zeros((0,), jnp.int32)
-    warm_all = (
-        jnp.concatenate(warms).astype(bool) if warms else jnp.zeros((0,), bool)
-    )
+    count = compile_count()
+    compiles = count.compiles
+    with TraceAnnotation("sched.fused") as call:
+        if not interpret:
+            ops.require_tpu("sched_many_fused")
+        idle, conns = state.idle, state.conns
+        n = events.shape[0]
+        ws, warms = [], []
+        padded = 0
+        for lo in range(0, n, chunk):
+            with TraceAnnotation("sched.stage_in"):
+                ev = events[lo : lo + chunk]
+                tail = chunk - ev.shape[0]
+                if tail:
+                    # pad the ragged last chunk with kind=3 no-op events
+                    # (func/worker 0 keep the row loads in bounds; an unknown
+                    # kind updates nothing) so every dispatch shares one
+                    # compiled (chunk,) shape
+                    pad = jnp.zeros((tail, 3), jnp.int32).at[:, 0].set(3)
+                    ev = jnp.concatenate([ev, pad])
+                kinds, funcs, workers = ev[:, 0], ev[:, 1], ev[:, 2]
+            with TraceAnnotation("sched.launch"):
+                a, warm, idle, conns = ops.sched_events(
+                    kinds, funcs, workers, idle, conns, interpret=interpret
+                )
+            with TraceAnnotation("sched.stage_out"):
+                if tail:
+                    a, warm = a[:-tail], warm[:-tail]
+            padded += tail
+            ws.append(a)
+            warms.append(warm)
+        with TraceAnnotation("sched.stage_out"):
+            ws_all = jnp.concatenate(ws) if ws else jnp.zeros((0,), jnp.int32)
+            warm_all = (
+                jnp.concatenate(warms).astype(bool) if warms else jnp.zeros((0,), bool)
+            )
+        call.set_metadata(chunks=len(ws), events=n, padded=padded,
+                          compiles=count.compiles - compiles)
     return JIQState(idle, conns), (ws_all, warm_all)
 
 

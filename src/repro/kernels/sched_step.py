@@ -23,6 +23,10 @@ index.  Every update is a whole-row ``where(lane == w, ...)`` written back as
 a row: the TPU compiler rejects single-lane dynamic slices on the lane axis,
 and its argmin handles float32 only, so the lowest-index argmin is a row min
 followed by the min lane index attaining it.
+
+The ``pallas_call`` is named ``_sched_events``, exactly: the chip
+benchmark's kernel matcher finds the custom call by that name in the device
+trace, whatever the jit wrapper around it is called.
 """
 
 from __future__ import annotations
@@ -128,4 +132,5 @@ def sched_events(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes(F, W)),
         interpret=interpret,
+        name="_sched_events",
     )(kinds, funcs, workers, idle, conns)

@@ -27,6 +27,7 @@ oracle.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import jax
@@ -143,9 +144,10 @@ def sched_many_fused(
     """``sched_many`` with the whole stream fused into chunked Pallas dispatches.
 
     Each ``chunk`` of mixed (ARRIVAL|FINISH|EVICT) events costs *one* kernel
-    dispatch (kernels/sched_step.sched_events) instead of one scan iteration
-    per event; state is carried between chunks.  Bit-exact against
-    ``sched_many(state, events, key=None)``.
+    launch (kernels/sched_step.sched_events) instead of one scan iteration
+    per event; state is carried between chunks.  The whole call is one
+    jitted program (:func:`_fused_call`), compiled once per stream length
+    and chunk.  Bit-exact against ``sched_many(state, events, key=None)``.
 
     A PRNG ``key`` asks for randomized tie-breaks, which only the scan path
     draws, so that call is ``sched_many``.  Otherwise the kernel runs
@@ -157,11 +159,12 @@ def sched_many_fused(
     ``sched.fused`` around the whole call, with the metadata ``chunks``,
     ``events`` (real events handed in), ``padded`` (no-op events added to
     fill the last chunk) and ``compiles`` (backend compiles during the call,
-    :func:`repro.utils.compile_cache.compile_count`); and per chunk
-    ``sched.stage_in`` (slice, pad and move the events to the device, split
-    the columns), ``sched.launch`` (``ops.sched_events``) and
-    ``sched.stage_out`` (unpad), with one more ``sched.stage_out`` around
-    the final concatenation of the answers.
+    :func:`repro.utils.compile_cache.compile_count`); and inside it, once
+    each, ``sched.stage_in`` (the host's checks of the backend and the
+    table's VMEM need), ``sched.launch`` (the dispatch of the one program,
+    which also moves host events to the device: on the TPU host that costs
+    less than a ``jax.device_put`` of them first) and ``sched.stage_out``
+    (the result built from its outputs).
     """
     if key is not None:
         return sched_many(state, events, key)
@@ -170,42 +173,47 @@ def sched_many_fused(
     count = compile_count()
     compiles = count.compiles
     with TraceAnnotation("sched.fused") as call:
-        if not interpret:
-            ops.require_tpu("sched_many_fused")
-        idle, conns = state.idle, state.conns
-        n = events.shape[0]
-        ws, warms = [], []
-        padded = 0
-        for lo in range(0, n, chunk):
-            with TraceAnnotation("sched.stage_in"):
-                ev = events[lo : lo + chunk]
-                tail = chunk - ev.shape[0]
-                if tail:
-                    # pad the ragged last chunk with kind=3 no-op events
-                    # (func/worker 0 keep the row loads in bounds; an unknown
-                    # kind updates nothing) so every dispatch shares one
-                    # compiled (chunk,) shape
-                    pad = jnp.zeros((tail, 3), jnp.int32).at[:, 0].set(3)
-                    ev = jnp.concatenate([ev, pad])
-                kinds, funcs, workers = ev[:, 0], ev[:, 1], ev[:, 2]
-            with TraceAnnotation("sched.launch"):
-                a, warm, idle, conns = ops.sched_events(
-                    kinds, funcs, workers, idle, conns, interpret=interpret
-                )
-            with TraceAnnotation("sched.stage_out"):
-                if tail:
-                    a, warm = a[:-tail], warm[:-tail]
-            padded += tail
-            ws.append(a)
-            warms.append(warm)
-        with TraceAnnotation("sched.stage_out"):
-            ws_all = jnp.concatenate(ws) if ws else jnp.zeros((0,), jnp.int32)
-            warm_all = (
-                jnp.concatenate(warms).astype(bool) if warms else jnp.zeros((0,), bool)
+        with TraceAnnotation("sched.stage_in"):
+            ops.check_sched_events("sched_many_fused", state.idle.shape, interpret)
+        with TraceAnnotation("sched.launch"):
+            idle, conns, assign, warm = _fused_call(
+                events, state.idle, state.conns, chunk=chunk, interpret=interpret
             )
-        call.set_metadata(chunks=len(ws), events=n, padded=padded,
+        with TraceAnnotation("sched.stage_out"):
+            out = JIQState(idle, conns), (assign, warm)
+        n = events.shape[0]
+        chunks = -(-n // chunk)
+        call.set_metadata(chunks=chunks, events=n, padded=chunks * chunk - n,
                           compiles=count.compiles - compiles)
-    return JIQState(idle, conns), (ws_all, warm_all)
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _fused_call(events, idle, conns, chunk, interpret):
+    """One ``sched_many_fused`` call: pad the ``(n, 3)`` stream to whole
+    chunks, run the kernel chunk by chunk with the state carried, and cut
+    the answers back to ``n``.  Returns ``(idle', conns', assign, warm)``."""
+    from ..kernels import ops
+
+    n = events.shape[0]
+    chunks = -(-n // chunk)
+    # pad the ragged last chunk with kind=3 no-op events (func/worker 0 keep
+    # the row loads in bounds; an unknown kind updates nothing) so every
+    # launch shares one (chunk,) kernel shape
+    pad = jnp.zeros((chunks * chunk - n, 3), jnp.int32).at[:, 0].set(3)
+    ev = jnp.concatenate([events, pad]).reshape(chunks, chunk, 3)
+
+    def run(carry, e):
+        a, warm, idle, conns = ops._sched_events(
+            e[:, 0], e[:, 1], e[:, 2], *carry, interpret=interpret
+        )
+        return (idle, conns), (a, warm)
+
+    if chunks == 1:
+        (idle, conns), (a, warm) = run((idle, conns), ev[0])
+    else:
+        (idle, conns), (a, warm) = jax.lax.scan(run, (idle, conns), ev)
+    return idle, conns, a.reshape(-1)[:n], warm.reshape(-1)[:n].astype(bool)
 
 
 def sched_many_adaptive(

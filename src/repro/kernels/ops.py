@@ -66,24 +66,32 @@ def require_tpu(what: str) -> None:
         )
 
 
+def check_sched_events(what: str, idle_shape, interpret: bool) -> None:
+    """Raise unless the native kernel can run an ``(F, W)`` idle table here:
+    the TPU (:func:`require_tpu`) and a table that fits
+    :data:`SCHED_VMEM_CAP_BYTES`.  Interpret mode needs neither."""
+    if interpret:
+        return
+    require_tpu(what)
+    F, W = idle_shape
+    need = _ss.vmem_bytes(F, W)
+    if need > SCHED_VMEM_CAP_BYTES:
+        raise ValueError(
+            f"{what}: {F} functions x {W} workers needs "
+            f"{need} bytes of VMEM, over the {SCHED_VMEM_CAP_BYTES}-byte "
+            "cap; split the workers into shards of fewer lanes"
+        )
+
+
 def sched_events(kinds, funcs, workers, idle, conns, interpret: bool = False):
     """Fused mixed (ARRIVAL|FINISH|EVICT) burst: pad lanes, run, unpad.
 
     Returns ``(assign (R,), warm (R,) int32, idle' (F, W), conns' (W,))``.
-    Natively this needs the TPU (:func:`require_tpu`) and a table that fits
-    :data:`SCHED_VMEM_CAP_BYTES`; a wider cluster raises rather than
+    Natively this needs the TPU and a table that fits the VMEM cap
+    (:func:`check_sched_events`); a wider cluster raises rather than
     running elsewhere.
     """
-    if not interpret:
-        require_tpu("ops.sched_events")
-        F, W = idle.shape
-        need = _ss.vmem_bytes(F, W)
-        if need > SCHED_VMEM_CAP_BYTES:
-            raise ValueError(
-                f"ops.sched_events: {F} functions x {W} workers needs "
-                f"{need} bytes of VMEM, over the {SCHED_VMEM_CAP_BYTES}-byte "
-                "cap; split the workers into shards of fewer lanes"
-            )
+    check_sched_events("ops.sched_events", idle.shape, interpret)
     return _sched_events(kinds, funcs, workers, idle, conns, interpret=interpret)
 
 

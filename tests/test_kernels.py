@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.jax_sched import JIQState, sched_many_fused
 from repro.kernels import ops, ref, sched_step
 
 jax.config.update("jax_enable_x64", False)
@@ -170,3 +171,8 @@ def test_sched_events_native_guards(monkeypatch):
     with pytest.raises(ValueError, match=f"{W} workers"):
         ops.sched_events(*shapes, jax.ShapeDtypeStruct((F, W), jnp.int32),
                          jax.ShapeDtypeStruct((W,), jnp.int32))
+    # the whole-stream wrapper checks the same before its one program
+    wide = JIQState(jax.ShapeDtypeStruct((F, W), jnp.int32),
+                    jax.ShapeDtypeStruct((W,), jnp.int32))
+    with pytest.raises(ValueError, match=f"sched_many_fused: {F} functions x {W} workers"):
+        sched_many_fused(wide, jnp.zeros((8, 3), jnp.int32))

@@ -120,24 +120,29 @@ def test_jax_sched_evict():
     assert int(state.idle.sum()) == 0
 
 
-def test_sched_many_fused_matches_scan():
-    """Chunked fused dispatch (interpret mode) == event-by-event scan."""
+@pytest.mark.parametrize("as_array", [np.asarray, jnp.asarray], ids=["numpy", "jnp"])
+@pytest.mark.parametrize("n", [1, 63, 64, 65, 160])
+def test_sched_many_fused_matches_scan(n, as_array):
+    """Chunked fused dispatch (interpret mode) == event-by-event scan, at
+    stream lengths on both sides of the 64-event chunk's boundaries, from
+    a host or a device array."""
     from repro.core import sched_many_fused
 
     rng = np.random.default_rng(5)
     state = init_state(6, 9)
     events = []
-    for _ in range(150):
+    for _ in range(n):
         k = int(rng.integers(0, 3))
         events.append((k, int(rng.integers(0, 6)), -1 if k == ARRIVAL else int(rng.integers(0, 9))))
-    ev = jnp.array(events, jnp.int32)
-    s1, (ws1, warm1) = sched_many(state, ev)
-    s2, (ws2, warm2) = sched_many_fused(state, ev, chunk=64, interpret=True)
+    ev = np.array(events, np.int32)
+    s1, (ws1, warm1) = sched_many(state, jnp.asarray(ev))
+    s2, (ws2, warm2) = sched_many_fused(state, as_array(ev), chunk=64, interpret=True)
+    assert ws2.shape == (n,) and ws2.dtype == jnp.int32 and warm2.dtype == bool
     assert jnp.all(ws1 == ws2) and jnp.all(warm1 == warm2)
     assert jnp.all(s1.idle == s2.idle) and jnp.all(s1.conns == s2.conns)
     # off the TPU the native kernel raises, naming the backend it found
     with pytest.raises(RuntimeError, match=repr(jax.default_backend())):
-        sched_many_fused(state, ev)
+        sched_many_fused(state, as_array(ev))
 
 
 def _mixed_events(rng, n, n_funcs=6, n_workers=9):

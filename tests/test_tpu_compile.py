@@ -2,7 +2,8 @@
 
 The TPU compiler refuses what interpret mode accepts: lane-axis slices it
 cannot prove aligned, integer argmin, and more scoped VMEM than the limit.
-These tests compile the fused dispatch kernel at cluster widths and the
+These tests compile the fused dispatch kernel at cluster widths, the
+dispatch wrapper's whole-call program at the benchmark's shape, and the
 full-width served model's steps for one v5e chip, so such faults show here
 at no chip time.  Nothing runs; a compile that passes is not a chip run.
 
@@ -21,6 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.core import jax_sched
 from repro.kernels import ops
 from repro.models import build_model, unzip
 
@@ -71,6 +73,24 @@ def test_sched_events_compiles_for_v5e(one_chip, n_workers, monkeypatch):
     # the chip benchmark finds the kernel in the device trace by this name
     assert re.search(r"%_sched_events(\.\d+)? = .*custom-call\(", text)
     assert compiled.memory_analysis().argument_size_in_bytes >= F * n_workers * 4
+
+
+@pytest.mark.parametrize("n_events", [1, 35])
+def test_sched_many_fused_call_compiles_for_v5e(one_chip, n_events):
+    """``sched_many_fused``'s whole-call program at the chip benchmark's
+    shape, the paper's 40 functions x 5 workers, for bursts of 1 and 35
+    events: the kernel keeps its ``_sched_events`` name inside it."""
+    F, W = 40, 5
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
+    compiled = (
+        jax_sched._fused_call
+        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=1024, interpret=False)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the chip benchmark finds the kernel in the device trace by this name
+    assert re.search(r"%_sched_events(\.\d+)? = .*custom-call\(", text)
 
 
 @pytest.mark.parametrize("step", ["prefill", "decode_step"])

@@ -13,7 +13,11 @@ script exits nonzero before any phase and prints no result.
   warm flags and the final ``idle``/``conns`` must equal ``sched_many`` (the
   ``lax.scan``) on the chip and the host ``HikuScheduler`` with lowest-id
   ties.  One chunk at 100,000 workers, from the state a 20,000-event prefix
-  leaves, is checked the same way.
+  leaves, is checked the same way.  Then the idle table that VMEM cannot
+  hold: 8,192 functions (Azure-skewed) x 5,000 workers from a seeded start
+  state, a seeded 2,000-event stream of all four kinds (two chunks) and a
+  534-event one from the state it leaves, through ``sched_many_fused`` (the
+  HBM form) against ``sched_many``, bit-exact.
 * **served** — ``ServingEngine`` under the ``hiku`` scheduler, 2 workers,
   3 endpoints of the full-width ``mamba2_130m`` (seeds 0-2), 8 requests:
   cold starts and warm hits on both workers; every request for an endpoint
@@ -42,12 +46,13 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 from repro.configs import get_config  # noqa: E402
 from repro.core import HikuScheduler, JIQState, sched_many, sched_many_fused  # noqa: E402
 from repro.core.jax_sched import ARRIVAL, EVICT, FINISH  # noqa: E402
-from repro.core.trace import make_functions  # noqa: E402
+from repro.core.trace import azure_like_weights, make_functions  # noqa: E402
 from repro.serving import Endpoint, Instance, ServingEngine  # noqa: E402
 from repro.utils.compile_cache import compile_count, enable_compile_cache  # noqa: E402
 
 CHUNK = 1024
 N_WORKERS, N_CHUNKS, WIDE_WORKERS = 1600, 64, 100_000
+HBM_FUNCS, HBM_WORKERS, HBM_EVENTS = 8192, 5000, (2000, 534)
 GEN_LEN = 4
 # a served token may trail the CPU reference's best logit by this many
 # standard deviations of the logits (matmul precision differs between the
@@ -172,9 +177,42 @@ def check_dispatch(n_workers, n_events, seed, n_prefix=0, interpret=False):
     )
 
 
+def check_dispatch_hbm(seed):
+    """The HBM form: a table past the VMEM cap, two calls with the state
+    carried, against the scan over both streams."""
+    rng = np.random.default_rng(seed)
+    idle0 = rng.integers(0, 3, (HBM_FUNCS, HBM_WORKERS), dtype=np.int32)
+    conns0 = rng.integers(0, 5, HBM_WORKERS, dtype=np.int32)
+    p = azure_like_weights(HBM_FUNCS, 0, population=HBM_FUNCS)
+    n = sum(HBM_EVENTS)
+    kinds = rng.integers(0, 4, n)
+    ev = np.stack([kinds, rng.choice(HBM_FUNCS, n, p=p),
+                   np.where(kinds == ARRIVAL, -1, rng.integers(0, HBM_WORKERS, n))],
+                  1).astype(np.int32)
+    state = JIQState(jnp.asarray(idle0), jnp.asarray(conns0))
+    laid_out = state.idle.format.layout.major_to_minor
+    cut = HBM_EVENTS[0]
+    mid, (w1, warm1) = sched_many_fused(state, ev[:cut], chunk=CHUNK)
+    end, (w2, warm2) = sched_many_fused(mid, ev[cut:], chunk=CHUNK)
+    scan_state, (s_w, s_warm) = sched_many(state, jnp.asarray(ev))
+    _equal("hbm assign vs scan", np.concatenate([w1, w2]), s_w)
+    _equal("hbm warm vs scan", np.concatenate([warm1, warm2]), s_warm)
+    _equal("hbm idle vs scan", end.idle, scan_state.idle)
+    _equal("hbm conns vs scan", end.conns, scan_state.conns)
+    counts = np.bincount(kinds, minlength=4)
+    print(
+        f"dispatch hbm W={HBM_WORKERS} F={HBM_FUNCS} events={cut}+{n - cut} "
+        f"(arrival/finish/evict/no-op={'/'.join(map(str, counts))}, "
+        f"warm={int(np.asarray(s_warm).sum())}; table layout {laid_out} in, "
+        f"{end.idle.format.layout.major_to_minor} out): fused == scan",
+        flush=True,
+    )
+
+
 def dispatch_phase(seed):
     check_dispatch(N_WORKERS, N_CHUNKS * CHUNK, seed)
     check_dispatch(WIDE_WORKERS, CHUNK, seed + 1, n_prefix=20_000)
+    check_dispatch_hbm(seed + 2)
 
 
 def reference_gaps(ep, prompt, toks):

@@ -32,6 +32,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..utils.compile_cache import compile_count
@@ -149,6 +150,12 @@ def sched_many_fused(
     jitted program (:func:`_fused_call`), compiled once per stream length
     and chunk.  Bit-exact against ``sched_many(state, events, key=None)``.
 
+    The table's shape picks the kernel's form
+    (``kernels/ops.check_sched_events``): the whole table in VMEM where it
+    fits, else the table in HBM with the part of its function's row that
+    an event needs brought in per event (``sched_step.sched_events_hbm``).
+    The table goes in and comes out in its default layout on either form.
+
     A PRNG ``key`` asks for randomized tie-breaks, which only the scan path
     draws, so that call is ``sched_many``.  Otherwise the kernel runs
     natively, which needs the TPU: on any other backend this raises unless
@@ -158,41 +165,52 @@ def sched_many_fused(
     they cost about a microsecond each when no trace is active):
     ``sched.fused`` around the whole call, with the metadata ``chunks``,
     ``events`` (real events handed in), ``padded`` (no-op events added to
-    fill the last chunk) and ``compiles`` (backend compiles during the call,
-    :func:`repro.utils.compile_cache.compile_count`); and inside it, once
-    each, ``sched.stage_in`` (the host's checks of the backend and the
-    table's VMEM need), ``sched.launch`` (the dispatch of the one program,
-    which also moves host events to the device: on the TPU host that costs
-    less than a ``jax.device_put`` of them first) and ``sched.stage_out``
-    (the result built from its outputs).
+    fill the last chunk), ``compiles`` (backend compiles during the call,
+    :func:`repro.utils.compile_cache.compile_count`), ``table`` (the form,
+    ``"vmem"`` or ``"hbm"``) and, where ``events`` is a host (NumPy) array,
+    ``row_loads`` (loads of function rows the kernel makes from HBM,
+    counted from the events by the kernel's rule,
+    ``sched_step.hbm_row_loads``; 0 on the VMEM form; left out for a device
+    array, which it would have to fetch); and inside it, once each,
+    ``sched.stage_in`` (the host's checks of the backend and the table's
+    VMEM need), ``sched.launch`` (the dispatch of the one program, which
+    also moves host events to the device: on the TPU host that costs less
+    than a ``jax.device_put`` of them first) and ``sched.stage_out`` (the
+    result built from its outputs).
     """
     if key is not None:
         return sched_many(state, events, key)
     from ..kernels import ops  # deferred: ops imports models/ (via ref)
+    from ..kernels.sched_step import hbm_row_loads
 
     count = compile_count()
     compiles = count.compiles
     with TraceAnnotation("sched.fused") as call:
         with TraceAnnotation("sched.stage_in"):
-            ops.check_sched_events("sched_many_fused", state.idle.shape, interpret)
+            table = ops.check_sched_events("sched_many_fused", state.idle.shape, interpret)
         with TraceAnnotation("sched.launch"):
             idle, conns, assign, warm = _fused_call(
-                events, state.idle, state.conns, chunk=chunk, interpret=interpret
+                events, state.idle, state.conns, chunk=chunk, interpret=interpret, table=table
             )
         with TraceAnnotation("sched.stage_out"):
             out = JIQState(idle, conns), (assign, warm)
         n = events.shape[0]
         chunks = -(-n // chunk)
-        call.set_metadata(chunks=chunks, events=n, padded=chunks * chunk - n,
-                          compiles=count.compiles - compiles)
+        meta = dict(chunks=chunks, events=n, padded=chunks * chunk - n,
+                    compiles=count.compiles - compiles, table=table)
+        if isinstance(events, np.ndarray):
+            meta["row_loads"] = 0 if table == "vmem" else hbm_row_loads(events[:, 0])
+        call.set_metadata(**meta)
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def _fused_call(events, idle, conns, chunk, interpret):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "table"))
+def _fused_call(events, idle, conns, chunk, interpret, table):
     """One ``sched_many_fused`` call: pad the ``(n, 3)`` stream to whole
     chunks, run the kernel chunk by chunk with the state carried, and cut
-    the answers back to ``n``.  Returns ``(idle', conns', assign, warm)``."""
+    the answers back to ``n``.  Returns ``(idle', conns', assign, warm)``.
+    The HBM form copies the table in the first chunk's kernel and updates
+    that copy in place in the later chunks."""
     from ..kernels import ops
 
     n = events.shape[0]
@@ -203,16 +221,22 @@ def _fused_call(events, idle, conns, chunk, interpret):
     pad = jnp.zeros((chunks * chunk - n, 3), jnp.int32).at[:, 0].set(3)
     ev = jnp.concatenate([events, pad]).reshape(chunks, chunk, 3)
 
-    def run(carry, e):
+    def run(carry, e, in_place=False):
         a, warm, idle, conns = ops._sched_events(
-            e[:, 0], e[:, 1], e[:, 2], *carry, interpret=interpret
+            e[:, 0], e[:, 1], e[:, 2], *carry, interpret=interpret, table=table,
+            in_place=in_place,
         )
         return (idle, conns), (a, warm)
 
     if chunks == 1:
         (idle, conns), (a, warm) = run((idle, conns), ev[0])
-    else:
+    elif table == "vmem":
         (idle, conns), (a, warm) = jax.lax.scan(run, (idle, conns), ev)
+    else:
+        carry, first = run((idle, conns), ev[0])
+        (idle, conns), rest = jax.lax.scan(
+            functools.partial(run, in_place=True), carry, ev[1:])
+        a, warm = (jnp.concatenate([x[None], y]) for x, y in zip(first, rest))
     return idle, conns, a.reshape(-1)[:n], warm.reshape(-1)[:n].astype(bool)
 
 

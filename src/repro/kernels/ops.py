@@ -66,37 +66,56 @@ def require_tpu(what: str) -> None:
         )
 
 
-def check_sched_events(what: str, idle_shape, interpret: bool) -> None:
-    """Raise unless the native kernel can run an ``(F, W)`` idle table here:
-    the TPU (:func:`require_tpu`) and a table that fits
-    :data:`SCHED_VMEM_CAP_BYTES`.  Interpret mode needs neither."""
-    if interpret:
-        return
-    require_tpu(what)
+def check_sched_events(what: str, idle_shape, interpret: bool) -> str:
+    """Where the kernel keeps an ``(F, W)`` idle table, chosen from its
+    shape: ``"vmem"`` (``sched_step.sched_events``) wherever
+    ``sched_step.vmem_bytes`` fits :data:`SCHED_VMEM_CAP_BYTES`, else
+    ``"hbm"`` (``sched_step.sched_events_hbm``).  Natively this raises
+    unless the backend is the TPU (:func:`require_tpu`) and the HBM form's
+    VMEM need (``sched_step.hbm_vmem_bytes``, O(W)) fits the cap;
+    interpret mode needs neither."""
     F, W = idle_shape
-    need = _ss.vmem_bytes(F, W)
-    if need > SCHED_VMEM_CAP_BYTES:
+    table = "vmem" if _ss.vmem_bytes(F, W) <= SCHED_VMEM_CAP_BYTES else "hbm"
+    if interpret:
+        return table
+    require_tpu(what)
+    need = _ss.hbm_vmem_bytes(W)
+    if table == "hbm" and need > SCHED_VMEM_CAP_BYTES:
         raise ValueError(
-            f"{what}: {F} functions x {W} workers needs "
-            f"{need} bytes of VMEM, over the {SCHED_VMEM_CAP_BYTES}-byte "
-            "cap; split the workers into shards of fewer lanes"
+            f"{what}: {F} functions x {W} workers needs {need} bytes of VMEM "
+            f"even with the table in HBM, over the "
+            f"{SCHED_VMEM_CAP_BYTES}-byte cap; split the workers into shards "
+            "of fewer lanes"
         )
+    return table
 
 
 def sched_events(kinds, funcs, workers, idle, conns, interpret: bool = False):
-    """Fused mixed (ARRIVAL|FINISH|EVICT) burst: pad lanes, run, unpad.
+    """Fused mixed (ARRIVAL|FINISH|EVICT) burst.
 
     Returns ``(assign (R,), warm (R,) int32, idle' (F, W), conns' (W,))``.
-    Natively this needs the TPU and a table that fits the VMEM cap
-    (:func:`check_sched_events`); a wider cluster raises rather than
-    running elsewhere.
+    The table's shape picks the kernel's form (:func:`check_sched_events`);
+    natively this needs the TPU, and a cluster too wide for either form
+    raises rather than running elsewhere.
     """
-    check_sched_events("ops.sched_events", idle.shape, interpret)
-    return _sched_events(kinds, funcs, workers, idle, conns, interpret=interpret)
+    table = check_sched_events("ops.sched_events", idle.shape, interpret)
+    return _sched_events(kinds, funcs, workers, idle, conns, interpret=interpret,
+                         table=table)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _sched_events(kinds, funcs, workers, idle, conns, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "table", "in_place"))
+def _sched_events(kinds, funcs, workers, idle, conns, interpret, table,
+                  in_place=False):
+    """The kernel in the form ``table`` names.  The VMEM form pads W to a
+    lane multiple and cuts it back; the HBM form takes W as it is, and with
+    ``in_place`` updates ``idle`` where it lies
+    (``sched_step.sched_events_hbm``)."""
+    if table == "hbm":
+        a, warm, idle2, conns2 = _ss.sched_events_hbm(
+            kinds, funcs, workers, idle, conns[None, :], in_place=in_place,
+            interpret=interpret,
+        )
+        return a, warm, idle2, conns2[0]
     F, W = idle.shape
     padW = (-W) % 128 if not interpret else 0
     if padW:

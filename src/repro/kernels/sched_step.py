@@ -24,19 +24,48 @@ a row: the TPU compiler rejects single-lane dynamic slices on the lane axis,
 and its argmin handles float32 only, so the lowest-index argmin is a row min
 followed by the min lane index attaining it.
 
-The ``pallas_call`` is named ``_sched_events``, exactly: the chip
-benchmark's kernel matcher finds the custom call by that name in the device
-trace, whatever the jit wrapper around it is called.
+``sched_events_hbm`` is the same kernel for a table that VMEM cannot hold
+(:func:`vmem_bytes` past the cap ``ops.py`` sets): the table stays in HBM
+and only the connection row is resident.  It works on the table's
+worker-major view ``idle.T``, ``(W, F)``, functions on the lanes: the TPU
+lays an ``(F, W)`` int32 array out by default with the side that pads less
+to 128 on the lanes, which is F wherever W pads more (8,192 x 5,000 among
+them), so there the view costs no copy, and the table crosses the
+program's boundary in its default layout (JAX's persistent compile cache
+does not keep a non-default one).
+The kernel copies the input table to its output with one HBM-to-HBM DMA.
+An ARRIVAL then brings the ``(W, 128)`` column block that holds its
+function's row into VMEM, transposes it to rows, runs the VMEM kernel's
+row logic on the row and writes back the ``(8, 128)`` tile that holds the
+one count it may change; a FINISH or EVICT brings in and writes back that
+tile alone; a no-op loads nothing.  The TPU compiler slices an HBM table
+only in whole tiles, so a block spans the rows up to the next multiple of
+8 (all rows of a table of fewer than 8 workers) and 128 lanes; rows past W
+are tile padding, masked with +INF connections and written back as they
+came.
+
+The ``pallas_call`` is named ``_sched_events`` (``_sched_events_hbm`` for
+the HBM form): the chip benchmark's kernel matcher finds the custom call by
+that prefix in the device trace, whatever the jit wrapper around it is
+called.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _INF = 2**30  # python int: jnp scalars would be captured as kernel constants
+_TILE_ROWS = 8  # sublanes of an int32 (8, 128) tile
+
+
+def _lanes(n_workers: int) -> int:
+    return -(-n_workers // 128) * 128
 
 
 def vmem_bytes(n_funcs: int, n_workers: int) -> int:
@@ -44,15 +73,64 @@ def vmem_bytes(n_funcs: int, n_workers: int) -> int:
     (``(8, 128)`` int32 tiles) and the connection row, each in and out, plus
     24 ``(1, W)`` rows for the event loop's temporaries (the v5e compiler
     takes up to ~19 when the limit allows) and 1 MiB."""
-    lanes = -(-n_workers // 128) * 128
+    lanes = _lanes(n_workers)
     sublanes = -(-n_funcs // 8) * 8
     return 4 * lanes * (2 * (sublanes + 1) + 24) + 2**20
+
+
+def hbm_vmem_bytes(n_workers: int) -> int:
+    """Scoped VMEM :func:`sched_events_hbm` asks for at ``W`` workers,
+    whatever the number of functions: the ``(W, 128)`` column block, its
+    transpose and as much again for the transposition's temporaries, the
+    connection row in, out and resident, the same 24 rows of temporaries
+    as :func:`vmem_bytes`, one ``(8, 128)`` tile, and 1 MiB."""
+    return 4 * _lanes(n_workers) * (3 * 128 + 3 + 24) + 4 * _TILE_ROWS * 128 + 2**20
+
+
+def hbm_row_loads(kinds) -> int:
+    """Loads from HBM of (part of) a function's row that
+    :func:`sched_events_hbm` makes for a host array of event kinds: one per
+    ARRIVAL (the column block holding the whole row), FINISH or EVICT (the
+    tile holding its one count), kinds 0-2; any other kind, such as the
+    padding's no-op 3, loads none."""
+    kinds = np.asarray(kinds)
+    return int(np.count_nonzero((kinds >= 0) & (kinds <= 2)))
 
 
 def _first_argmin(scores, lane):
     """Lowest lane index attaining the row minimum (``jnp.argmin``'s tie)."""
     m = jnp.min(scores)
     return jnp.min(jnp.where(scores == m, lane, _INF))
+
+
+def _event(k, w_ev, lane, load_row, load_conns):
+    """One event on its function's idle row and the connection row, both
+    ``(1, lanes)``.  The two callables load them, after the kind flags, as
+    the VMEM kernel always has.  Returns ``(row', conns', is_arr, w_assign,
+    warm)``; any kind but 0-2 changes nothing."""
+    is_arr = (k == 0).astype(jnp.int32)
+    is_fin = (k == 1).astype(jnp.int32)
+    is_evt = (k == 2).astype(jnp.int32)
+
+    row = load_row()
+    conns = load_conns()
+    has_idle = jnp.max(row) > 0
+    w_pull = _first_argmin(jnp.where(row > 0, conns, _INF), lane)
+    w_fb = _first_argmin(conns, lane)
+    w_assign = jnp.where(has_idle, w_pull, w_fb)
+
+    # ARRIVAL: dequeue from PQ_f (if pulled) + open connection
+    dec = is_arr * has_idle.astype(jnp.int32)
+    at_assign = lane == w_assign
+    row = row - jnp.where(at_assign, dec, 0)
+    conns = conns + jnp.where(at_assign, is_arr, 0)
+
+    # FINISH: pull enqueue + close connection; EVICT: notification removal
+    at_ev = lane == w_ev  # ARRIVAL carries -1: matches no lane
+    row = row + jnp.where(at_ev, is_fin, 0)
+    row = row - jnp.where(at_ev & (row > 0), is_evt, 0)
+    conns = jnp.where(at_ev, jnp.maximum(conns - is_fin, 0), conns)
+    return row, conns, is_arr, w_assign, dec
 
 
 def _sched_events_kernel(
@@ -67,30 +145,10 @@ def _sched_events_kernel(
     def body(i, _):
         k = kinds_ref[i]
         f = funcs_ref[i]
-        w_ev = workers_ref[i]  # ARRIVAL carries -1: matches no lane
-        is_arr = (k == 0).astype(jnp.int32)
-        is_fin = (k == 1).astype(jnp.int32)
-        is_evt = (k == 2).astype(jnp.int32)
-
-        row = idle_out[pl.ds(f, 1), :]  # (1, W)
-        conns = conns_out[...]
-        has_idle = jnp.max(row) > 0
-        w_pull = _first_argmin(jnp.where(row > 0, conns, _INF), lane)
-        w_fb = _first_argmin(conns, lane)
-        w_assign = jnp.where(has_idle, w_pull, w_fb)
-
-        # ARRIVAL: dequeue from PQ_f (if pulled) + open connection
-        dec = is_arr * has_idle.astype(jnp.int32)
-        at_assign = lane == w_assign
-        row = row - jnp.where(at_assign, dec, 0)
-        conns = conns + jnp.where(at_assign, is_arr, 0)
-
-        # FINISH: pull enqueue + close connection; EVICT: notification removal
-        at_ev = lane == w_ev
-        row = row + jnp.where(at_ev, is_fin, 0)
-        row = row - jnp.where(at_ev & (row > 0), is_evt, 0)
-        conns = jnp.where(at_ev, jnp.maximum(conns - is_fin, 0), conns)
-
+        row, conns, is_arr, w_assign, dec = _event(
+            k, workers_ref[i], lane,
+            lambda: idle_out[pl.ds(f, 1), :], lambda: conns_out[...],
+        )
         idle_out[pl.ds(f, 1), :] = row
         conns_out[...] = conns
         assign_ref[i] = jnp.where(is_arr == 1, w_assign, -1)
@@ -98,6 +156,93 @@ def _sched_events_kernel(
         return 0
 
     jax.lax.fori_loop(0, R, body, 0)
+
+
+def _column_rows(n_workers: int) -> int:
+    """Rows of the worker-major table that a DMA of a column block spans:
+    W up to whole 8-row tiles, or all W rows of a table of fewer."""
+    return n_workers if n_workers < _TILE_ROWS else -(-n_workers // _TILE_ROWS) * _TILE_ROWS
+
+
+def _sched_events_hbm_kernel(
+    n_workers, copy, kinds_ref, funcs_ref, workers_ref, table_ref, conns_ref,
+    assign_ref, warm_ref, table_out, conns_out, cols, rows, tile, conns, sem,
+):
+    if copy:
+        whole = pltpu.make_async_copy(table_ref, table_out, sem)
+        whole.start()
+    n_rows = _column_rows(n_workers)
+    n_tile = tile.shape[0]
+    lanes = conns.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    real = lane < n_workers  # rows past W are tile padding
+    sub = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    fn = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    conns[...] = jnp.full((1, lanes), _INF, jnp.int32)
+    conns[:, pl.ds(0, n_workers)] = conns_ref[...]
+    if copy:
+        whole.wait()
+
+    def dma(src, dst):
+        c = pltpu.make_async_copy(src, dst, sem)
+        c.start()
+        c.wait()
+
+    def tile_of(w, fb):
+        """First row of the tile holding worker ``w``'s counts of functions
+        ``fb..fb+127``, and that tile in HBM."""
+        if n_tile == n_rows:  # the tile is every row
+            base = 0
+        else:
+            base = pl.multiple_of((w // n_tile) * n_tile, n_tile)
+        return base, table_out.at[pl.ds(base, n_tile), pl.ds(fb, 128)]
+
+    def body(i, _):
+        k = kinds_ref[i]
+        f = funcs_ref[i]
+        w_ev = workers_ref[i]
+        fb = pl.multiple_of((f // 128) * 128, 128)
+        at_f = fn == f - fb
+        assign_ref[i] = -1
+        warm_ref[i] = 0
+
+        @pl.when(k == 0)
+        def _arrival():
+            dma(table_out.at[pl.ds(0, n_rows), pl.ds(fb, 128)], cols.at[pl.ds(0, n_rows), :])
+            rows[...] = cols[...].T
+            raw = rows[pl.ds(f - fb, 1), :]
+            _, c, _, w_assign, dec = _event(
+                k, w_ev, lane, lambda: jnp.where(real, raw, 0), lambda: conns[...],
+            )
+            conns[...] = c
+            # the ARRIVAL changes at most the count at (w_assign, f)
+            base, hbm = tile_of(w_assign, fb)
+            block = cols[pl.ds(base, n_tile), :]
+            at = (sub + base == w_assign) & at_f
+            cols[pl.ds(base, n_tile), :] = block - jnp.where(at, dec, 0)
+            dma(cols.at[pl.ds(base, n_tile), :], hbm)
+            assign_ref[i] = w_assign
+            warm_ref[i] = dec
+
+        @pl.when((k == 1) | (k == 2))
+        def _notice():
+            # FINISH: pull enqueue + close connection; EVICT: notification
+            # removal (the VMEM kernel's rules, on the one count they touch)
+            is_fin = (k == 1).astype(jnp.int32)
+            is_evt = (k == 2).astype(jnp.int32)
+            base, hbm = tile_of(jnp.clip(w_ev, 0, n_workers - 1), fb)
+            dma(hbm, tile)
+            at = (sub + base == w_ev) & at_f  # a worker past W matches nothing
+            block = tile[...] + jnp.where(at, is_fin, 0)
+            tile[...] = block - jnp.where(at & (block > 0), is_evt, 0)
+            dma(tile, hbm)
+            c = conns[...]
+            conns[...] = jnp.where(lane == w_ev, jnp.maximum(c - is_fin, 0), c)
+
+        return 0
+
+    jax.lax.fori_loop(0, kinds_ref.shape[0], body, 0)
+    conns_out[...] = conns[:, pl.ds(0, n_workers)]
 
 
 def sched_events(
@@ -134,3 +279,73 @@ def sched_events(
         interpret=interpret,
         name="_sched_events",
     )(kinds, funcs, workers, idle, conns)
+
+
+def _as_in_hbm(table):
+    """The worker-major ``table`` ``(W, F)`` as the chip's HBM holds it, for
+    the interpreter: rows padded to whole 8-row tiles (a table of fewer
+    keeps them) and lanes to a multiple of 128, the padding holding idle
+    counts no worker or function has, which the kernel must mask and leave
+    as they are."""
+    W, F = table.shape
+    return jnp.pad(table, ((0, _column_rows(W) - W), (0, -(-F // 128) * 128 - F)),
+                   constant_values=1)
+
+
+def sched_events_hbm(
+    kinds: jax.Array,    # (R,) int32 — 0 ARRIVAL / 1 FINISH / 2 EVICT
+    funcs: jax.Array,    # (R,) int32
+    workers: jax.Array,  # (R,) int32 (-1 for ARRIVAL)
+    idle: jax.Array,     # (F, W) int32, in HBM
+    conns: jax.Array,    # (1, W) int32
+    in_place: bool = False,
+    interpret: bool = False,
+):
+    """:func:`sched_events` with the idle table left in HBM: the same
+    arguments, results and semantics, for any ``(F, W)`` whose
+    :func:`hbm_vmem_bytes` fits VMEM.  W is taken as it is.
+
+    The kernel works on ``idle.T`` (see the module's notes: no copy where
+    the TPU lays ``idle`` out worker-major, as it does where W pads more to
+    128 than F; XLA transposes around the kernel for other shapes) and
+    writes its whole output table: one HBM-to-HBM DMA of the input, then
+    the tiles the events change.  ``in_place=True`` makes the output alias
+    the input and copies nothing, for a table the caller may overwrite (one
+    its own program made).  Each ARRIVAL, FINISH and EVICT loads from HBM
+    once (:func:`hbm_row_loads`).  ``interpret=True`` runs it in the TPU
+    interpreter (its DMAs included) on the table padded as HBM holds it
+    (:func:`_as_in_hbm`).
+    """
+    R = kinds.shape[0]
+    F, W = idle.shape
+    table = idle.T
+    if interpret:
+        table = _as_in_hbm(table)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    row = pl.BlockSpec((1, W), lambda: (0, 0))
+    a, warm, table2, conns2 = pl.pallas_call(
+        functools.partial(_sched_events_hbm_kernel, W, not in_place),
+        in_specs=[smem, smem, smem, hbm, row],
+        out_specs=[smem, smem, hbm, row],
+        out_shape=[
+            jax.ShapeDtypeStruct((R,), jnp.int32),
+            jax.ShapeDtypeStruct((R,), jnp.int32),
+            jax.ShapeDtypeStruct(table.shape, jnp.int32),
+            jax.ShapeDtypeStruct((1, W), jnp.int32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((_lanes(W), 128), jnp.int32),
+            pltpu.VMEM((128, _lanes(W)), jnp.int32),
+            pltpu.VMEM((min(W, _TILE_ROWS), 128), jnp.int32),
+            pltpu.VMEM((1, _lanes(W)), jnp.int32),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+        input_output_aliases={3: 2} if in_place else {},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=hbm_vmem_bytes(W)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="_sched_events_hbm",
+    )(kinds, funcs, workers, table, conns)
+    if interpret:
+        table2 = table2[:W, :F]
+    return a, warm, table2.T, conns2

@@ -3,6 +3,10 @@
 tests live in test_kernels_properties.py so this module runs even where
 hypothesis isn't installed."""
 
+import functools
+import runpy
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +16,12 @@ from repro.core.jax_sched import JIQState, sched_many_fused
 from repro.kernels import ops, ref, sched_step
 
 jax.config.update("jax_enable_x64", False)
+
+# the chip benchmark's plain reference: Algorithm 1 by whole-array scans,
+# importing nothing of the program
+brute_force = runpy.run_path(
+    str(Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / "reference.py")
+)["brute_force"]
 
 
 def _tol(dtype):
@@ -155,24 +165,64 @@ def test_sched_events_sweep(R, F, W, arrivals_only):
         assert jnp.all(i2 == ir) and jnp.all(c2 == cr)
 
 
+@pytest.mark.parametrize(
+    "R,F,W",
+    [(64, 1, 5), (65, 24, 130), (63, 40, 200), (160, 40, 5), (1, 24, 200),
+     (100, 1, 130), (160, 300, 13), (70, 260, 200)],
+)
+def test_sched_events_hbm_sweep(R, F, W, monkeypatch):
+    """The HBM form (the table left in HBM, the part of its function's row
+    an event needs brought in per event) == the jax_sched scan oracle and
+    the benchmark's plain reference, on mixed streams of all four kinds (3
+    is a no-op), with W off the 8-row tiles and F off the 128-lane tiles.
+    A VMEM cap just below the VMEM form's need makes ``ops.sched_events``
+    take it (interpret mode holds the HBM form to no cap)."""
+    monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
+    assert ops.check_sched_events("test", (F, W), interpret=True) == "hbm"
+    rng = np.random.default_rng(R * 1000 + F * 10 + W)
+    kinds = rng.integers(0, 4, R)
+    funcs = rng.integers(0, F, R)
+    workers = np.where(kinds == 0, -1, rng.integers(0, W, R))
+    idle = rng.integers(0, 3, (F, W))
+    conns = rng.integers(0, 5, W)
+    args = [jnp.asarray(a, jnp.int32) for a in (kinds, funcs, workers, idle, conns)]
+    run = functools.partial(ops.sched_events, interpret=True)
+    assert "_sched_events_hbm" in str(jax.make_jaxpr(run)(*args))
+    got = [np.asarray(x) for x in run(*args)]
+    want = [np.asarray(x) for x in ref.sched_events_ref(*args)]
+    plain = brute_force(idle, conns, np.stack([kinds, funcs, workers], 1))
+    for g, w, b in zip(got, want, plain):
+        assert np.array_equal(g, w) and np.array_equal(g, np.asarray(b, g.dtype))
+
+
 def test_sched_events_native_guards(monkeypatch):
     """The native kernel never runs elsewhere: off the TPU it raises naming
-    the backend, and a table wider than the VMEM cap raises naming the
+    the backend.  A table wider than the VMEM form's cap takes the HBM
+    form, and a width whose rows alone exceed the cap raises naming the
     width instead of running another path."""
     shapes = [jax.ShapeDtypeStruct(s, jnp.int32) for s in [(8,), (8,), (8,)]]
     with pytest.raises(RuntimeError, match="'cpu'"):
         ops.sched_events(*shapes, jnp.zeros((4, 8), jnp.int32),
                          jnp.zeros((8,), jnp.int32))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    F = 40
-    W = next(w for w in range(128, 10**6, 128)
-             if sched_step.vmem_bytes(F, w) > ops.SCHED_VMEM_CAP_BYTES)
-    assert sched_step.vmem_bytes(F, W - 128) <= ops.SCHED_VMEM_CAP_BYTES
-    with pytest.raises(ValueError, match=f"{W} workers"):
-        ops.sched_events(*shapes, jax.ShapeDtypeStruct((F, W), jnp.int32),
-                         jax.ShapeDtypeStruct((W,), jnp.int32))
+    W = 5000
+    F = next(f for f in range(8, 10**5, 8)
+             if sched_step.vmem_bytes(f, W) > ops.SCHED_VMEM_CAP_BYTES)
+    assert ops.check_sched_events("t", (F - 8, W), interpret=False) == "vmem"
+    assert ops.check_sched_events("t", (F, W), interpret=False) == "hbm"
+    native = functools.partial(ops.sched_events, interpret=False)
+    jaxpr = str(jax.make_jaxpr(native)(*shapes, jax.ShapeDtypeStruct((F, W), jnp.int32),
+                                       jax.ShapeDtypeStruct((W,), jnp.int32)))
+    assert "_sched_events_hbm" in jaxpr
+    # the published 8,192 x 5,000 cluster is past the VMEM form and inside the HBM form
+    assert ops.check_sched_events("t", (8192, 5000), interpret=False) == "hbm"
+    wide = next(w for w in range(W, 10**7, 128)
+                if sched_step.hbm_vmem_bytes(w) > ops.SCHED_VMEM_CAP_BYTES)
+    with pytest.raises(ValueError, match=f"{wide} workers"):
+        ops.sched_events(*shapes, jax.ShapeDtypeStruct((F, wide), jnp.int32),
+                         jax.ShapeDtypeStruct((wide,), jnp.int32))
     # the whole-stream wrapper checks the same before its one program
-    wide = JIQState(jax.ShapeDtypeStruct((F, W), jnp.int32),
-                    jax.ShapeDtypeStruct((W,), jnp.int32))
-    with pytest.raises(ValueError, match=f"sched_many_fused: {F} functions x {W} workers"):
-        sched_many_fused(wide, jnp.zeros((8, 3), jnp.int32))
+    state = JIQState(jax.ShapeDtypeStruct((F, wide), jnp.int32),
+                     jax.ShapeDtypeStruct((wide,), jnp.int32))
+    with pytest.raises(ValueError, match=f"sched_many_fused: {F} functions x {wide} workers"):
+        sched_many_fused(state, jnp.zeros((8, 3), jnp.int32))

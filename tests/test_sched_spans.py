@@ -6,27 +6,36 @@ same clock as the device's operations: ``sched.fused`` per call, with its
 ``chunks``, ``events``, ``padded`` and ``compiles`` as metadata, and inside
 it one ``sched.stage_in``, one ``sched.launch`` (the call's one jitted
 program, whatever its number of chunks) and one ``sched.stage_out``.
+``sched.fused`` also names the kernel's form (``table``) and, for a host
+burst, the loads of function rows the kernel makes from HBM (``row_loads``).
 These tests trace calls on the CPU, with the kernel in Pallas's
 interpreter, and read the trace back with ``jax.profiler.ProfileData``.
 """
 
+import types
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.jax_sched import ARRIVAL, EVICT, FINISH, JIQState, sched_many_fused
+from repro.kernels import ops, sched_step
 from repro.utils.compile_cache import compile_count
+
+CHIP_BENCH = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 
 STAGES = ("sched.stage_in", "sched.launch", "sched.stage_out")
 
 
-def _events(n, n_funcs, n_workers, seed):
+def _events(n, n_funcs, n_workers, seed, kinds=(ARRIVAL, FINISH, EVICT),
+            p=(0.5, 0.4, 0.1)):
     """A mixed stream whose FINISH/EVICT notices name any worker: the
     scheduler's answers need not be meaningful, only equal run to run."""
     rng = np.random.default_rng(seed)
-    kinds = rng.choice([ARRIVAL, FINISH, EVICT], size=n, p=[0.5, 0.4, 0.1])
+    kinds = rng.choice(kinds, size=n, p=p)
     funcs = rng.integers(0, n_funcs, n)
     workers = np.where(kinds == ARRIVAL, -1, rng.integers(0, n_workers, n))
     return np.stack([kinds, funcs, workers], 1).astype(np.int32)
@@ -123,3 +132,105 @@ def test_the_compile_count_counts_a_fresh_jit_compile():
     jax.block_until_ready(jax.jit(lambda v: v * 3.0 + 0.25)(x))
     assert count.compiles == compiles + 1
     assert count.seconds > seconds
+
+
+def _in_hbm(monkeypatch, n_funcs, n_workers):
+    """A VMEM cap just below the VMEM form's need, so a small table takes
+    the HBM form (interpret mode holds that form to no cap)."""
+    monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(n_funcs, n_workers) - 1)
+
+
+@pytest.mark.parametrize("table", ["vmem", "hbm"])
+def test_fused_span_names_the_table_form_and_its_row_loads(tmp_path, monkeypatch, table):
+    """``table`` names the form the shape chose; ``row_loads`` counts the
+    ARRIVAL, FINISH and EVICT events of a host burst on the HBM form (no-op
+    kind 3 loads nothing) and is 0 on the VMEM form.  A device burst leaves
+    ``row_loads`` out rather than fetch it."""
+    if table == "hbm":
+        _in_hbm(monkeypatch, 24, 130)
+    state = _state(24, 130)
+    ev = _events(150, 24, 130, seed=7, kinds=(ARRIVAL, FINISH, EVICT, 3),
+                 p=(0.4, 0.3, 0.1, 0.2))
+    loads = int(np.isin(ev[:, 0], (ARRIVAL, FINISH, EVICT)).sum())
+    assert 0 < loads < len(ev)
+
+    def calls():
+        host = sched_many_fused(state, ev, chunk=64, interpret=True)
+        jax.block_until_ready(host)
+        return sched_many_fused(state, jnp.asarray(ev), chunk=64, interpret=True)
+
+    _, spans = _traced(tmp_path, calls)
+    host, device = [s[3] for s in spans if s[0] == "sched.fused"]
+    assert host["table"] == device["table"] == table
+    assert int(host["row_loads"]) == (loads if table == "hbm" else 0)
+    assert "row_loads" not in device
+
+
+def test_row_loads_are_the_rows_the_kernel_loads(monkeypatch):
+    """On a seeded one-chunk stream the span's ``row_loads`` is what the HBM
+    kernel brings in: the TPU interpreter numbers every DMA a kernel starts,
+    and the kernel starts one whole-table copy plus, per row load, a load
+    and a store: an ARRIVAL's column block in and the tile it changes out,
+    a FINISH's or EVICT's tile in and out."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as interpreter
+
+    _in_hbm(monkeypatch, 40, 200)
+    state = _state(40, 200)
+
+    def dmas(ev):
+        pltpu.reset_tpu_interpret_mode_state()
+        jax.block_until_ready(sched_many_fused(state, ev, chunk=256, interpret=True))
+        return interpreter.dma_id_counter.value
+
+    noop = np.zeros((200, 3), np.int32)
+    noop[:, 0] = 3
+    ev = _events(200, 40, 200, seed=8, kinds=(ARRIVAL, FINISH, EVICT, 3),
+                 p=(0.4, 0.3, 0.1, 0.2))
+    assert dmas(ev) - dmas(noop) == 2 * sched_step.hbm_row_loads(ev[:, 0])
+    assert sched_step.hbm_row_loads(ev[:, 0]) == int((ev[:, 0] != 3).sum())
+
+
+def test_hbm_answers_and_state_are_the_same_with_a_trace_active(tmp_path, monkeypatch):
+    _in_hbm(monkeypatch, 24, 130)
+    state, ev = _state(24, 130), _events(300, 24, 130, seed=9)
+    call = lambda: sched_many_fused(state, ev, chunk=128, interpret=True)  # noqa: E731
+    plain = jax.device_get(call())
+    traced, spans = _traced(tmp_path, call)
+    assert [s[0] for s in spans].count("sched.fused") == 1
+    for got, want in zip(jax.tree.leaves(jax.device_get(traced)), jax.tree.leaves(plain)):
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
+def test_the_benchmark_reads_ns_per_row_load_from_the_spans(tmp_path, monkeypatch):
+    """The chip benchmark's ``sched_kernel.ns_per_row_load`` divides the
+    kernel's device time by the ``row_loads`` of the window's calls, and
+    reads nothing where the calls load no rows (a table in VMEM)."""
+    import importlib.util
+
+    monkeypatch.syspath_prepend(str(CHIP_BENCH))
+    spec = importlib.util.spec_from_file_location(
+        "ns_per_row_load", CHIP_BENCH / "metrics" / "sched_kernel.ns_per_row_load.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    def ctx(window):
+        trace = types.SimpleNamespace(
+            window=window, op_ns=lambda match: (6.0e6, 3) if match(
+                "_sched_events_hbm.1 custom-call") else (0.0, 0))
+        return types.SimpleNamespace(trace=trace)
+
+    ev = _events(100, 24, 130, seed=10)
+    _, spans = _traced(tmp_path / "vmem", lambda: sched_many_fused(
+        _state(24, 130), ev, chunk=128, interpret=True))
+    whole = (spans[0][1], spans[-1][2])
+    assert reader.read(ctx(whole), tmp_path / "vmem") is None
+    _in_hbm(monkeypatch, 24, 130)
+
+    def two_calls():
+        first = sched_many_fused(_state(24, 130), ev, chunk=128, interpret=True)
+        return sched_many_fused(first[0], ev[:40], chunk=128, interpret=True)
+
+    _, spans = _traced(tmp_path / "hbm", two_calls)
+    fused = [s for s in spans if s[0] == "sched.fused"]
+    assert reader.read(ctx((fused[0][1], fused[1][2])), tmp_path / "hbm") == 6.0e6 / 140
+    assert reader.read(ctx((fused[1][1], fused[1][2])), tmp_path / "hbm") == 6.0e6 / 40

@@ -3,6 +3,9 @@
 The hypothesis-based python<->jax equivalence property test lives in
 test_scheduler_properties.py so this module runs without hypothesis."""
 
+import runpy
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -143,6 +146,44 @@ def test_sched_many_fused_matches_scan(n, as_array):
     # off the TPU the native kernel raises, naming the backend it found
     with pytest.raises(RuntimeError, match=repr(jax.default_backend())):
         sched_many_fused(state, as_array(ev))
+
+
+# the chip benchmark's plain reference: Algorithm 1 by whole-array scans,
+# importing nothing of the program
+brute_force = runpy.run_path(
+    str(Path(__file__).resolve().parents[1] / "benchmarks" / "chip" / "reference.py")
+)["brute_force"]
+
+
+@pytest.mark.parametrize("F,W", [(1, 5), (24, 130), (40, 200)])
+@pytest.mark.parametrize("n", [1, 63, 64, 65, 160])
+def test_sched_many_fused_hbm_matches_scan(n, F, W, monkeypatch):
+    """The HBM form through ``sched_many_fused`` (interpret mode, a VMEM
+    cap just below the VMEM form's need) == the event-by-event scan and the plain
+    reference, bitwise, on mixed streams of all four kinds, across the
+    64-event chunk's boundaries (several chunks: the first copies the
+    table, the rest update it in place) and with the state carried into a
+    second call."""
+    from repro.core import JIQState, sched_many_fused
+    from repro.kernels import ops, sched_step
+
+    monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
+    rng = np.random.default_rng(n * 100 + F)
+    kinds = rng.integers(0, 4, 2 * n)
+    ev = np.stack([kinds, rng.integers(0, F, 2 * n),
+                   np.where(kinds == ARRIVAL, -1, rng.integers(0, W, 2 * n))], 1)
+    ev = ev.astype(np.int32)
+    idle, conns = rng.integers(0, 3, (F, W)), rng.integers(0, 5, W)
+    state = JIQState(jnp.asarray(idle, jnp.int32), jnp.asarray(conns, jnp.int32))
+    mid, (ws_a, warm_a) = sched_many_fused(state, ev[:n], chunk=64, interpret=True)
+    end, (ws_b, warm_b) = sched_many_fused(mid, ev[n:], chunk=64, interpret=True)
+    fused = (np.concatenate([ws_a, ws_b]), np.concatenate([warm_a, warm_b]),
+             np.asarray(end.idle), np.asarray(end.conns))
+    s1, (ws1, warm1) = sched_many(state, jnp.asarray(ev))
+    for got, want in zip(fused, (ws1, warm1, s1.idle, s1.conns)):
+        assert np.array_equal(got, np.asarray(want))
+    for got, want in zip(fused, brute_force(idle, conns, ev)):
+        assert np.array_equal(got, want)
 
 
 def _mixed_events(rng, n, n_funcs=6, n_workers=9):

@@ -146,9 +146,11 @@ def sched_many_fused(
 
     Each ``chunk`` of mixed (ARRIVAL|FINISH|EVICT) events costs *one* kernel
     launch (kernels/sched_step.sched_events) instead of one scan iteration
-    per event; state is carried between chunks.  The whole call is one
-    jitted program (:func:`_fused_call`), compiled once per stream length
-    and chunk.  Bit-exact against ``sched_many(state, events, key=None)``.
+    per event, and a ragged tail one launch of exactly its length
+    (:func:`_split`): no launch runs an event it was not given.  State is
+    carried between launches.  The whole call is one jitted program
+    (:func:`_fused_call`), compiled once per stream length and chunk.
+    Bit-exact against ``sched_many(state, events, key=None)``.
 
     The table's shape picks the kernel's form
     (``kernels/ops.check_sched_events``): the whole table in VMEM where it
@@ -163,9 +165,11 @@ def sched_many_fused(
 
     The kernel path writes profiler spans (``jax.profiler.TraceAnnotation``;
     they cost about a microsecond each when no trace is active):
-    ``sched.fused`` around the whole call, with the metadata ``chunks``,
-    ``events`` (real events handed in), ``padded`` (no-op events added to
-    fill the last chunk), ``compiles`` (backend compiles during the call,
+    ``sched.fused`` around the whole call, with the metadata ``chunks``
+    (kernel launches), ``events`` (real events handed in), ``padded``
+    (events the launches run past those handed in, from the same
+    :func:`_split` as the program: 0, as no launch is padded),
+    ``compiles`` (backend compiles during the call,
     :func:`repro.utils.compile_cache.compile_count`), ``table`` (the form,
     ``"vmem"`` or ``"hbm"``) and, where ``events`` is a host (NumPy) array,
     ``row_loads`` (loads of function rows the kernel makes from HBM,
@@ -195,8 +199,8 @@ def sched_many_fused(
         with TraceAnnotation("sched.stage_out"):
             out = JIQState(idle, conns), (assign, warm)
         n = events.shape[0]
-        chunks = -(-n // chunk)
-        meta = dict(chunks=chunks, events=n, padded=chunks * chunk - n,
+        full, tail = _split(n, chunk)
+        meta = dict(chunks=full + (tail > 0), events=n, padded=full * chunk + tail - n,
                     compiles=count.compiles - compiles, table=table)
         if isinstance(events, np.ndarray):
             meta["row_loads"] = 0 if table == "vmem" else hbm_row_loads(events[:, 0])
@@ -204,40 +208,47 @@ def sched_many_fused(
     return out
 
 
+def _split(n: int, chunk: int) -> Tuple[int, int]:
+    """The kernel launches of an ``n``-event call: ``full`` launches of
+    ``chunk`` events, then, where ``tail`` > 0, one of ``tail``."""
+    return divmod(n, chunk)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret", "table"))
 def _fused_call(events, idle, conns, chunk, interpret, table):
-    """One ``sched_many_fused`` call: pad the ``(n, 3)`` stream to whole
-    chunks, run the kernel chunk by chunk with the state carried, and cut
-    the answers back to ``n``.  Returns ``(idle', conns', assign, warm)``.
-    The HBM form copies the table in the first chunk's kernel and updates
-    that copy in place in the later chunks."""
+    """One ``sched_many_fused`` call: run the kernel over the ``(n, 3)``
+    stream in the launches :func:`_split` gives, the whole chunks (a
+    ``lax.scan`` past the first) and then the tail, with the state carried.
+    Returns ``(idle', conns', assign, warm)``, the answers being the
+    launches' own outputs in order.  The first launch of the HBM form
+    copies the table and every later one updates that copy in place (the
+    VMEM form ignores ``in_place``).  No events make no launch."""
     from ..kernels import ops
 
-    n = events.shape[0]
-    chunks = -(-n // chunk)
-    # pad the ragged last chunk with kind=3 no-op events (func/worker 0 keep
-    # the row loads in bounds; an unknown kind updates nothing) so every
-    # launch shares one (chunk,) kernel shape
-    pad = jnp.zeros((chunks * chunk - n, 3), jnp.int32).at[:, 0].set(3)
-    ev = jnp.concatenate([events, pad]).reshape(chunks, chunk, 3)
+    full, tail = _split(events.shape[0], chunk)
 
-    def run(carry, e, in_place=False):
+    def run(carry, e, in_place):
         a, warm, idle, conns = ops._sched_events(
             e[:, 0], e[:, 1], e[:, 2], *carry, interpret=interpret, table=table,
             in_place=in_place,
         )
         return (idle, conns), (a, warm)
 
-    if chunks == 1:
-        (idle, conns), (a, warm) = run((idle, conns), ev[0])
-    elif table == "vmem":
-        (idle, conns), (a, warm) = jax.lax.scan(run, (idle, conns), ev)
-    else:
-        carry, first = run((idle, conns), ev[0])
-        (idle, conns), rest = jax.lax.scan(
-            functools.partial(run, in_place=True), carry, ev[1:])
-        a, warm = (jnp.concatenate([x[None], y]) for x, y in zip(first, rest))
-    return idle, conns, a.reshape(-1)[:n], warm.reshape(-1)[:n].astype(bool)
+    carry, answers = (idle, conns), []
+    whole = events[: full * chunk].reshape(full, chunk, 3)
+    if full:
+        carry, first = run(carry, whole[0], in_place=False)
+        answers.append(first)
+    if full > 1:
+        carry, rest = jax.lax.scan(functools.partial(run, in_place=True), carry, whole[1:])
+        answers.append(jax.tree.map(lambda x: x.reshape(-1), rest))
+    if tail:
+        carry, last = run(carry, events[full * chunk:], in_place=full > 0)
+        answers.append(last)
+    if not answers:
+        return idle, conns, jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool)
+    a, warm = (jnp.concatenate(x) for x in zip(*answers))
+    return *carry, a, warm.astype(bool)
 
 
 def sched_many_adaptive(
@@ -257,9 +268,9 @@ def sched_many_adaptive(
     — e.g. ``Simulator.heap_density`` readings taken ahead of the clock —
     else the window's own event count, a pure stream-rate proxy) and the
     detector's answer picks the dispatch path: ``chunk == 1`` steps the
-    window through the ``lax.scan`` path (sparse streams never pay
-    kernel-launch padding for mostly-empty chunks), anything larger fuses
-    the window through :func:`sched_many_fused` with that chunk.
+    window through the ``lax.scan`` path (a sparse stream pays no kernel
+    launch), anything larger fuses the window through
+    :func:`sched_many_fused` with that chunk.
 
     The detector is a pure observer — event order is untouched — so the
     result is **bitwise equal** to ``sched_many(state, events)`` for every

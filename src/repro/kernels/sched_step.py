@@ -91,8 +91,8 @@ def hbm_row_loads(kinds) -> int:
     """Loads from HBM of (part of) a function's row that
     :func:`sched_events_hbm` makes for a host array of event kinds: one per
     ARRIVAL (the column block holding the whole row), FINISH or EVICT (the
-    tile holding its one count), kinds 0-2; any other kind, such as the
-    padding's no-op 3, loads none."""
+    tile holding its one count), kinds 0-2; any other kind, a no-op, loads
+    none."""
     kinds = np.asarray(kinds)
     return int(np.count_nonzero((kinds >= 0) & (kinds <= 2)))
 

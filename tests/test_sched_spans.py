@@ -76,7 +76,7 @@ def test_one_call_leaves_one_fused_span_with_its_stages_inside(tmp_path):
     assert len(fused) == 1
     _, lo, hi, stats = fused[0]
     assert {k: stats[k] for k in ("chunks", "events", "padded")} == {
-        "chunks": 3, "events": 2500, "padded": 3 * 1024 - 2500}
+        "chunks": 3, "events": 2500, "padded": 0}
     names = [s[0] for s in spans if s[0] != "sched.fused"]
     assert names == list(STAGES)
     assert all(lo <= s[1] <= s[2] <= hi for s in spans)
@@ -111,7 +111,21 @@ def test_compiles_are_counted_in_the_call_that_makes_them(tmp_path):
     first, again = [s[3] for s in spans if s[0] == "sched.fused"]
     assert first["compiles"] > 0
     assert again["compiles"] == 0
-    assert first["padded"] == again["padded"] == 2 * 256 - 300
+    assert first["padded"] == again["padded"] == 0
+
+
+@pytest.mark.parametrize("n,chunk", [(192, 64), (64, 64), (150, 64), (20, 64)],
+                         ids=["whole-chunks", "one-chunk", "ragged-tail", "tail-only"])
+def test_chunks_counts_the_launches_and_nothing_is_padded(tmp_path, n, chunk):
+    """Each launch runs exactly the events it is given: ``chunks`` is
+    ``ceil(n / chunk)`` (``n / chunk`` where it divides) and ``padded`` is
+    0, with or without a ragged tail."""
+    state, ev = _state(6, 9), _events(n, 6, 9, seed=11)
+    _, spans = _traced(tmp_path, lambda: sched_many_fused(
+        state, ev, chunk=chunk, interpret=True))
+    (stats,) = [s[3] for s in spans if s[0] == "sched.fused"]
+    assert {k: stats[k] for k in ("chunks", "events", "padded")} == {
+        "chunks": -(-n // chunk), "events": n, "padded": 0}
 
 
 def test_answers_and_state_are_the_same_with_a_trace_active(tmp_path):

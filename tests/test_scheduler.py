@@ -186,6 +186,28 @@ def test_sched_many_fused_hbm_matches_scan(n, F, W, monkeypatch):
         assert np.array_equal(got, want)
 
 
+@pytest.mark.parametrize("table", ["vmem", "hbm"])
+def test_sched_many_fused_empty_stream_changes_nothing(table, monkeypatch):
+    """No events make no kernel launch, on either form: the state comes
+    back bit for bit and the answers are empty, int32 and bool."""
+    from repro.core import JIQState, sched_many_fused
+    from repro.kernels import ops, sched_step
+
+    F, W = 24, 130
+    if table == "hbm":
+        monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
+    assert ops.check_sched_events("test", (F, W), interpret=True) == table
+    rng = np.random.default_rng(12)
+    idle, conns = rng.integers(0, 3, (F, W)), rng.integers(0, 5, W)
+    state = JIQState(jnp.asarray(idle, jnp.int32), jnp.asarray(conns, jnp.int32))
+    end, (ws, warm) = sched_many_fused(state, np.zeros((0, 3), np.int32), chunk=64,
+                                       interpret=True)
+    assert ws.shape == warm.shape == (0,)
+    assert ws.dtype == jnp.int32 and warm.dtype == bool
+    assert end.idle.dtype == end.conns.dtype == jnp.int32
+    assert np.array_equal(end.idle, idle) and np.array_equal(end.conns, conns)
+
+
 def _mixed_events(rng, n, n_funcs=6, n_workers=9):
     events = []
     for _ in range(n):

@@ -147,6 +147,41 @@ def test_sched_many_fused_hbm_call_compiles_for_v5e(one_chip, n_events):
     assert compiled.output_formats[0].layout == default.input_formats[0][0].layout
 
 
+def _launch_lengths(text, kernel):
+    """The events each launch of ``kernel`` in a compiled program runs:
+    the length of its three ``s32[R]`` event operands, in sorted order."""
+    return sorted(int(r) for r in re.findall(
+        rf"%{kernel}(?:\.\d+)? = .*custom-call\(.*operand_layout_constraints="
+        r"\{s32\[(\d+)\]\{0\}, s32\[\1\]\{0\}, s32\[\1\]\{0\}, ", text))
+
+
+@pytest.mark.parametrize("table,n_events,launches", [
+    ("vmem", 1, [1]), ("vmem", 35, [35]), ("hbm", 534, [534]), ("hbm", 1100, [76, 1024]),
+], ids=["vmem-1", "vmem-35", "hbm-534", "hbm-1100"])
+def test_sched_many_fused_launches_are_sized_to_the_burst(one_chip, table, n_events, launches):
+    """``sched_many_fused``'s whole-call program launches the kernel on
+    exactly the events it is given, at 1,024-event chunks: a burst shorter
+    than a chunk is one launch of its own length, with no array of a
+    chunk's length anywhere (nothing pads the events), and a longer one a
+    launch per whole chunk and one for the tail, the HBM form's tail
+    updating the table in place (no table copy outside the kernels)."""
+    F, W = (40, 5) if table == "vmem" else (8192, 5000)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
+    compiled = (
+        jax_sched._fused_call
+        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=1024, interpret=False,
+               table=table)
+        .compile()
+    )
+    text = compiled.as_text()
+    kernel = "_sched_events" if table == "vmem" else "_sched_events_hbm"
+    assert _launch_lengths(text, kernel) == launches
+    if n_events < 1024:
+        assert not re.search(r"s32\[1024[,\]]", text)
+    if table == "hbm":
+        _no_table_copy(compiled, F, W)
+
+
 @pytest.mark.parametrize("step", ["prefill", "decode_step"])
 def test_mamba2_130m_serve_step_compiles_for_v5e(one_chip, step):
     """The served endpoint at its published widths, as ``serving.Instance``

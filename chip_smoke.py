@@ -45,12 +45,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
 from repro.configs import get_config  # noqa: E402
 from repro.core import HikuScheduler, JIQState, sched_many, sched_many_fused  # noqa: E402
+from repro.core import jax_sched  # noqa: E402
 from repro.core.jax_sched import ARRIVAL, EVICT, FINISH  # noqa: E402
 from repro.core.trace import azure_like_weights, make_functions  # noqa: E402
 from repro.serving import Endpoint, Instance, ServingEngine  # noqa: E402
 from repro.utils.compile_cache import compile_count, enable_compile_cache  # noqa: E402
 
-CHUNK = 1024
 N_WORKERS, N_CHUNKS, WIDE_WORKERS = 1600, 64, 100_000
 HBM_FUNCS, HBM_WORKERS, HBM_EVENTS = 8192, 5000, (2000, 534)
 GEN_LEN = 4
@@ -156,9 +156,7 @@ def check_dispatch(n_workers, n_events, seed, n_prefix=0, interpret=False):
     kinds = np.bincount(ev[:, 0], minlength=3)
     state = JIQState(jnp.asarray(idle0), jnp.asarray(conns0))
     events = jnp.asarray(ev)
-    fused_state, (f_w, f_warm) = sched_many_fused(
-        state, events, chunk=CHUNK, interpret=interpret
-    )
+    fused_state, (f_w, f_warm) = sched_many_fused(state, events, interpret=interpret)
     scan_state, (s_w, s_warm) = sched_many(state, events)
     for ref, (r_w, r_warm, r_idle, r_conns) in {
         "scan": (s_w, s_warm, scan_state.idle, scan_state.conns),
@@ -192,8 +190,8 @@ def check_dispatch_hbm(seed):
     state = JIQState(jnp.asarray(idle0), jnp.asarray(conns0))
     laid_out = state.idle.format.layout.major_to_minor
     cut = HBM_EVENTS[0]
-    mid, (w1, warm1) = sched_many_fused(state, ev[:cut], chunk=CHUNK)
-    end, (w2, warm2) = sched_many_fused(mid, ev[cut:], chunk=CHUNK)
+    mid, (w1, warm1) = sched_many_fused(state, ev[:cut])
+    end, (w2, warm2) = sched_many_fused(mid, ev[cut:])
     scan_state, (s_w, s_warm) = sched_many(state, jnp.asarray(ev))
     _equal("hbm assign vs scan", np.concatenate([w1, w2]), s_w)
     _equal("hbm warm vs scan", np.concatenate([warm1, warm2]), s_warm)
@@ -210,8 +208,8 @@ def check_dispatch_hbm(seed):
 
 
 def dispatch_phase(seed):
-    check_dispatch(N_WORKERS, N_CHUNKS * CHUNK, seed)
-    check_dispatch(WIDE_WORKERS, CHUNK, seed + 1, n_prefix=20_000)
+    check_dispatch(N_WORKERS, N_CHUNKS * jax_sched.CHUNK, seed)
+    check_dispatch(WIDE_WORKERS, jax_sched.CHUNK, seed + 1, n_prefix=20_000)
     check_dispatch_hbm(seed + 2)
 
 

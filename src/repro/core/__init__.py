@@ -70,7 +70,6 @@ from .jax_sched import (
     JIQState,
     init_state,
     sched_many,
-    sched_many_adaptive,
     sched_many_fused,
     sched_step,
 )
@@ -101,7 +100,7 @@ from .shard import (
     StreamChunk,
     shard_seed,
 )
-from .simulator import BurstDetector, SalvagedVU, SimConfig, Simulator, StolenTask
+from .simulator import SalvagedVU, SimConfig, Simulator, StolenTask
 from .stealing import Migration, Salvage, drain_tick, steal_tick
 from .trace import FunctionSpec, default_n_events, make_functions, make_vu_programs
 from .workloads import Scenario, available_scenarios, make_scenario
@@ -117,7 +116,6 @@ __all__ = [
     "AutoscaleConfig",
     "Autoscaler",
     "BanditTuner",
-    "BurstDetector",
     "DurationEstimator",
     "EVICT",
     "EventPlane",
@@ -165,7 +163,6 @@ __all__ = [
     "replay_shards",
     "rolling_restart",
     "sched_many",
-    "sched_many_adaptive",
     "sched_many_fused",
     "sched_step",
     "scripts_from_run",

@@ -21,8 +21,7 @@ Random tie-breaking uses the Gumbel-max trick over exact ties, matching the
 "random selection from W_min" of the fallback mechanism.
 
 ``kernels/sched_step.py`` fuses mixed event bursts into one Pallas kernel
-(``sched_many_fused``); ``kernels/ref.py`` points back at this module as the
-oracle.
+(``sched_many_fused``); ``sched_many`` is the scan it is checked against.
 """
 
 from __future__ import annotations
@@ -35,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from ..kernels import sched_step as _ss
 from ..utils.compile_cache import compile_count
 
 ARRIVAL, FINISH, EVICT = 0, 1, 2
@@ -135,35 +135,35 @@ def sched_many(
     return jax.lax.scan(body, state, xs)
 
 
-def sched_many_fused(
-    state: JIQState,
-    events: jax.Array,
-    key: jax.Array | None = None,
-    chunk: int = 1024,
-    interpret: bool = False,
-) -> Tuple[JIQState, Tuple[jax.Array, jax.Array]]:
-    """``sched_many`` with the whole stream fused into chunked Pallas dispatches.
+# The longest kernel launch: a burst of more events splits into launches of
+# this many and one of the rest.
+CHUNK = 1024
 
-    Each ``chunk`` of mixed (ARRIVAL|FINISH|EVICT) events costs *one* kernel
-    launch (kernels/sched_step.sched_events) instead of one scan iteration
-    per event, and a ragged tail one launch of exactly its length
+
+def sched_many_fused(
+    state: JIQState, events: jax.Array, interpret: bool = False
+) -> Tuple[JIQState, Tuple[jax.Array, jax.Array]]:
+    """``sched_many`` with the whole stream fused into Pallas kernel launches.
+
+    Each :data:`CHUNK` of mixed (ARRIVAL|FINISH|EVICT) events costs *one*
+    kernel launch (``kernels/sched_step.launch``) instead of one scan
+    iteration per event, and a ragged tail one launch of exactly its length
     (:func:`_split`): no launch runs an event it was not given.  State is
     carried between launches.  The whole call is one jitted program
-    (:func:`_fused_call`), compiled once per stream length and chunk.
-    Bit-exact against ``sched_many(state, events, key=None)``.
+    (:func:`_fused_call`), compiled once per stream length.  Bit-exact
+    against ``sched_many(state, events, key=None)``; randomized tie-breaks
+    are ``sched_many``'s alone.
 
     The table's shape picks the kernel's form
-    (``kernels/ops.check_sched_events``): the whole table in VMEM where it
+    (``kernels/sched_step.table_form``): the whole table in VMEM where it
     fits, else the table in HBM with the part of its function's row that
     an event needs brought in per event (``sched_step.sched_events_hbm``).
     The table goes in and comes out in its default layout on either form.
+    The kernel runs natively, which needs the TPU: on any other backend
+    this raises unless ``interpret=True`` runs the kernel body in Pallas's
+    interpreter.
 
-    A PRNG ``key`` asks for randomized tie-breaks, which only the scan path
-    draws, so that call is ``sched_many``.  Otherwise the kernel runs
-    natively, which needs the TPU: on any other backend this raises unless
-    ``interpret=True`` runs the kernel body in Pallas's interpreter.
-
-    The kernel path writes profiler spans (``jax.profiler.TraceAnnotation``;
+    The call writes profiler spans (``jax.profiler.TraceAnnotation``;
     they cost about a microsecond each when no trace is active):
     ``sched.fused`` around the whole call, with the metadata ``chunks``
     (kernel launches), ``events`` (real events handed in), ``padded``
@@ -182,16 +182,12 @@ def sched_many_fused(
     than a ``jax.device_put`` of them first) and ``sched.stage_out`` (the
     result built from its outputs).
     """
-    if key is not None:
-        return sched_many(state, events, key)
-    from ..kernels import ops  # deferred: ops imports models/ (via ref)
-    from ..kernels.sched_step import hbm_row_loads
-
+    chunk = CHUNK
     count = compile_count()
     compiles = count.compiles
     with TraceAnnotation("sched.fused") as call:
         with TraceAnnotation("sched.stage_in"):
-            table = ops.check_sched_events("sched_many_fused", state.idle.shape, interpret)
+            table = _ss.table_form("sched_many_fused", state.idle.shape, interpret)
         with TraceAnnotation("sched.launch"):
             idle, conns, assign, warm = _fused_call(
                 events, state.idle, state.conns, chunk=chunk, interpret=interpret, table=table
@@ -203,7 +199,7 @@ def sched_many_fused(
         meta = dict(chunks=full + (tail > 0), events=n, padded=full * chunk + tail - n,
                     compiles=count.compiles - compiles, table=table)
         if isinstance(events, np.ndarray):
-            meta["row_loads"] = 0 if table == "vmem" else hbm_row_loads(events[:, 0])
+            meta["row_loads"] = 0 if table == "vmem" else _ss.hbm_row_loads(events[:, 0])
         call.set_metadata(**meta)
     return out
 
@@ -223,12 +219,10 @@ def _fused_call(events, idle, conns, chunk, interpret, table):
     launches' own outputs in order.  The first launch of the HBM form
     copies the table and every later one updates that copy in place (the
     VMEM form ignores ``in_place``).  No events make no launch."""
-    from ..kernels import ops
-
     full, tail = _split(events.shape[0], chunk)
 
     def run(carry, e, in_place):
-        a, warm, idle, conns = ops._sched_events(
+        a, warm, idle, conns = _ss.launch(
             e[:, 0], e[:, 1], e[:, 2], *carry, interpret=interpret, table=table,
             in_place=in_place,
         )
@@ -249,63 +243,6 @@ def _fused_call(events, idle, conns, chunk, interpret, table):
         return idle, conns, jnp.zeros((0,), jnp.int32), jnp.zeros((0,), bool)
     a, warm = (jnp.concatenate(x) for x in zip(*answers))
     return *carry, a, warm.astype(bool)
-
-
-def sched_many_adaptive(
-    state: JIQState,
-    events: jax.Array,
-    detector,
-    densities=None,
-    segment: int = 1024,
-    key: jax.Array | None = None,
-    interpret: bool = False,
-) -> Tuple[JIQState, Tuple[jax.Array, jax.Array]]:
-    """Burst-adaptive fused dispatch: ``sched_many`` with per-window chunk
-    sizes chosen by a :class:`~repro.core.simulator.BurstDetector`.
-
-    Walks the stream in ``segment``-event windows.  Before each window, one
-    density sample is folded into ``detector`` (``densities[i]`` when given
-    — e.g. ``Simulator.heap_density`` readings taken ahead of the clock —
-    else the window's own event count, a pure stream-rate proxy) and the
-    detector's answer picks the dispatch path: ``chunk == 1`` steps the
-    window through the ``lax.scan`` path (a sparse stream pays no kernel
-    launch), anything larger fuses the window through
-    :func:`sched_many_fused` with that chunk.
-
-    The detector is a pure observer — event order is untouched — so the
-    result is **bitwise equal** to ``sched_many(state, events)`` for every
-    detector state and density sequence (pinned in tests/test_scheduler.py).
-    With a PRNG ``key`` (randomized tie-breaks live in the scan path) the
-    whole stream takes the scan path unchanged.
-    """
-    if key is not None:
-        return sched_many(state, events, key)
-    if segment < 1:
-        raise ValueError(f"segment must be >= 1, got {segment}")
-    n = events.shape[0]
-    n_windows = -(-n // segment)
-    if densities is not None and len(densities) < n_windows:
-        raise ValueError(
-            f"densities has {len(densities)} samples for {n_windows} windows"
-        )
-    ws, warms = [], []
-    for i in range(n_windows):
-        ev = events[i * segment : (i + 1) * segment]
-        sample = float(densities[i]) if densities is not None else float(ev.shape[0])
-        chunk = detector.observe(sample)
-        if chunk <= 1:
-            state, (a, warm) = sched_many(state, ev, None)
-        else:
-            state, (a, warm) = sched_many_fused(
-                state, ev, chunk=chunk, interpret=interpret
-            )
-        ws.append(a)
-        warms.append(warm)
-    ws_all = jnp.concatenate(ws) if ws else jnp.zeros((0,), jnp.int32)
-    warm_all = (
-        jnp.concatenate(warms).astype(bool) if warms else jnp.zeros((0,), bool)
-    )
-    return state, (ws_all, warm_all)
 
 
 # ---------------------------------------------------------------- invariants

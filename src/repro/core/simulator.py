@@ -142,81 +142,12 @@ class SimConfig:
 # RequestRecord lives in core.records now; re-exported here for the legacy
 # import path (``from repro.core.simulator import RequestRecord``).
 __all__ = [
-    "BurstDetector",
     "RequestRecord",
     "SalvagedVU",
     "SimConfig",
     "Simulator",
     "StolenTask",
 ]
-
-
-class BurstDetector:
-    """EWMA + threshold burst detector over near-horizon event density.
-
-    The adaptive half of the fused-dispatch path (``jax_sched
-    .sched_many_adaptive``): callers feed it the event density ahead of the
-    clock — :meth:`Simulator.heap_density`, or events/s over an incoming
-    event window — and it answers with a dispatch chunk size.  A smoothed
-    density above a threshold selects that threshold's chunk (largest
-    first); below every threshold it falls back to ``base_chunk`` (1 =
-    single-event stepping), so sparse streams never pay kernel-launch
-    padding for mostly-empty chunks and bursts batch wide.
-
-    The EWMA (``ewma += alpha * (density - ewma)``; first observation
-    primes it) makes the choice hysteretic: one quiet window inside a burst
-    does not collapse the chunk size, and one spike does not inflate it.
-    Pure observer — it never touches event order, so dispatch results are
-    bitwise independent of the chunk choice (pinned in
-    tests/test_scheduler.py).
-
-    Args:
-        alpha: EWMA smoothing factor in (0, 1].
-        thresholds: ``((density, chunk), ...)`` sorted descending by
-            density; the first row whose density the EWMA meets wins.
-        base_chunk: chunk when the EWMA is below every threshold.
-    """
-
-    __slots__ = ("alpha", "thresholds", "base_chunk", "ewma", "_primed")
-
-    def __init__(
-        self,
-        alpha: float = 0.25,
-        thresholds: Tuple[Tuple[float, int], ...] = ((4096.0, 4096), (1024.0, 1024), (256.0, 256)),
-        base_chunk: int = 1,
-    ):
-        if not 0.0 < alpha <= 1.0:
-            raise ValueError(f"alpha must be in (0, 1], got {alpha}")
-        if base_chunk < 1:
-            raise ValueError(f"base_chunk must be >= 1, got {base_chunk}")
-        rows = tuple((float(d), int(c)) for d, c in thresholds)
-        if any(c < 1 for _, c in rows):
-            raise ValueError(f"chunk sizes must be >= 1, got {rows}")
-        if list(rows) != sorted(rows, reverse=True):
-            raise ValueError(f"thresholds must be sorted descending, got {rows}")
-        self.alpha = alpha
-        self.thresholds = rows
-        self.base_chunk = int(base_chunk)
-        self.ewma = 0.0
-        self._primed = False
-
-    def observe(self, density: float) -> int:
-        """Fold one density sample in; return the chunk size to use now."""
-        density = float(density)
-        if not self._primed:
-            self.ewma = density
-            self._primed = True
-        else:
-            self.ewma += self.alpha * (density - self.ewma)
-        return self.chunk
-
-    @property
-    def chunk(self) -> int:
-        """Current chunk choice for the smoothed density (no fold)."""
-        for thresh, chunk in self.thresholds:
-            if self.ewma >= thresh:
-                return chunk
-        return self.base_chunk
 
 
 # integer event kinds; the *push order* (and with it the tie-breaking
@@ -969,25 +900,6 @@ class Simulator:
         shards with nothing scheduled inside the tick — an O(1) peek, so an
         idle shard costs one comparison per tick instead of a call."""
         return self._heap[0][0] if self._heap else float("inf")
-
-    def heap_density(self, horizon_s: float = 0.25) -> float:
-        """Pending events per second inside the heap's near horizon.
-
-        Counts events within ``horizon_s`` of the earliest pending event —
-        the burst signal a :class:`BurstDetector` folds to pick dispatch
-        chunk sizes.  One O(heap) pass; meant to be sampled per dispatch
-        batch or per tick, never per event."""
-        if horizon_s <= 0:
-            raise ValueError(f"horizon_s must be > 0, got {horizon_s}")
-        heap = self._heap
-        if not heap:
-            return 0.0
-        hi = heap[0][0] + horizon_s
-        n = 0
-        for ev in heap:
-            if ev[0] <= hi:
-                n += 1
-        return n / horizon_s
 
     def attach_dirty(self, sink: set, idx: int) -> None:
         """Publish this shard's state changes into ``sink`` as index ``idx``.

@@ -7,7 +7,7 @@ Each function mirrors the exact contract of its kernel in ops.py:
                            models/mamba.py, re-exported for the sweep tests)
   sched_step_ref       <-> kernels/sched_step.py on ARRIVAL-only bursts
                            (vectorized Algorithm 1 over a request burst)
-  sched_events_ref     <-> kernels/sched_step.py (mixed event bursts)
+Mixed event bursts are checked against ``core.jax_sched.sched_many``.
 """
 
 from __future__ import annotations
@@ -101,18 +101,3 @@ def sched_step_ref(
     (idle, conns), (ws, warm) = jax.lax.scan(step, (idle, conns), funcs)
     return ws, warm, idle, conns
 
-
-def sched_events_ref(
-    kinds: jax.Array,    # (R,) int32 — 0 ARRIVAL / 1 FINISH / 2 EVICT
-    funcs: jax.Array,    # (R,) int32
-    workers: jax.Array,  # (R,) int32 (-1 for ARRIVAL)
-    idle: jax.Array,     # (F, W) int32
-    conns: jax.Array,    # (W,) int32
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Mixed-event oracle for kernels/sched_step.sched_events: the scan of
-    ``core.jax_sched.sched_step`` with deterministic ties (key=None)."""
-    from ..core.jax_sched import JIQState, sched_many
-
-    events = jnp.stack([kinds, funcs, workers], axis=1).astype(jnp.int32)
-    state, (ws, warm) = sched_many(JIQState(idle, conns), events, key=None)
-    return ws, warm.astype(jnp.int32), state.idle, state.conns

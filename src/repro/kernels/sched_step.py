@@ -16,7 +16,7 @@ notification removal.  Bit-exact against ``sched_many(..., key=None)``
 the vectorized Algorithm 1 of ``ref.sched_step_ref``.
 
 Layout: workers live on the 128-lane axis (W padded to a lane multiple by
-ops.py, padding masked with +INF connections); the whole idle table and the
+:func:`launch`, padding masked with +INF connections); the whole idle table and the
 ``(1, W)`` connection row are resident in VMEM; the event loop is a
 fori_loop that loads the event's function row with a dynamic *sublane*
 index.  Every update is a whole-row ``where(lane == w, ...)`` written back as
@@ -25,7 +25,7 @@ and its argmin handles float32 only, so the lowest-index argmin is a row min
 followed by the min lane index attaining it.
 
 ``sched_events_hbm`` is the same kernel for a table that VMEM cannot hold
-(:func:`vmem_bytes` past the cap ``ops.py`` sets): the table stays in HBM
+(:func:`vmem_bytes` past :data:`VMEM_CAP_BYTES`): the table stays in HBM
 and only the connection row is resident.  It works on the table's
 worker-major view ``idle.T``, ``(W, F)``, functions on the lanes: the TPU
 lays an ``(F, W)`` int32 array out by default with the side that pads less
@@ -349,3 +349,76 @@ def sched_events_hbm(
     if interpret:
         table2 = table2[:W, :F]
     return a, warm, table2.T, conns2
+
+
+# ------------------------------------------------------------ the wrapper
+# The choice between the two forms (the cap, the byte counts and the
+# backend check) and the one launch of either; nothing here imports the
+# rest of the package.
+
+# v5e holds 128 MiB of VMEM per core (pltpu.get_tpu_info); an eighth stays
+# free for Mosaic's internal scratch.
+VMEM_CAP_BYTES = 112 * 2**20
+
+
+def require_tpu(what: str) -> None:
+    """Raise unless JAX's default backend is the TPU: the native kernels
+    have no CPU/GPU lowering and nothing substitutes another path."""
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"{what} runs the native Pallas TPU kernel, but JAX's default "
+            f"backend is {backend!r}; pass interpret=True to run the kernel "
+            "body in Pallas's interpreter"
+        )
+
+
+def table_form(what: str, idle_shape, interpret: bool) -> str:
+    """Where the kernel keeps an ``(F, W)`` idle table, chosen from its
+    shape: ``"vmem"`` (:func:`sched_events`) wherever :func:`vmem_bytes`
+    fits :data:`VMEM_CAP_BYTES`, else ``"hbm"`` (:func:`sched_events_hbm`).
+    Natively this raises unless the backend is the TPU (:func:`require_tpu`)
+    and the HBM form's VMEM need (:func:`hbm_vmem_bytes`, O(W)) fits the
+    cap; interpret mode needs neither."""
+    F, W = idle_shape
+    table = "vmem" if vmem_bytes(F, W) <= VMEM_CAP_BYTES else "hbm"
+    if interpret:
+        return table
+    require_tpu(what)
+    need = hbm_vmem_bytes(W)
+    if table == "hbm" and need > VMEM_CAP_BYTES:
+        raise ValueError(
+            f"{what}: {F} functions x {W} workers needs {need} bytes of VMEM "
+            f"even with the table in HBM, over the "
+            f"{VMEM_CAP_BYTES}-byte cap; split the workers into shards "
+            "of fewer lanes"
+        )
+    return table
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "table", "in_place"))
+def launch(kinds, funcs, workers, idle, conns, interpret, table, in_place=False):
+    """One launch of the kernel in the form ``table`` names
+    (:func:`table_form`), on ``conns`` of shape ``(W,)``.  Returns
+    ``(assign, warm, idle', conns')``.  The VMEM form pads W to whole
+    128-lane rows, the padding masked with +INF connections, and cuts it
+    back; the HBM form takes W as it is, and with ``in_place`` updates
+    ``idle`` where it lies (:func:`sched_events_hbm`)."""
+    if table == "hbm":
+        a, warm, idle2, conns2 = sched_events_hbm(
+            kinds, funcs, workers, idle, conns[None, :], in_place=in_place,
+            interpret=interpret,
+        )
+        return a, warm, idle2, conns2[0]
+    W = idle.shape[1]
+    padW = _lanes(W) - W
+    if padW:
+        idle = jnp.pad(idle, ((0, 0), (0, padW)))
+        conns = jnp.pad(conns, (0, padW), constant_values=_INF)  # never selected
+    a, warm, idle2, conns2 = sched_events(
+        kinds, funcs, workers, idle, conns[None, :], interpret=interpret
+    )
+    conns2 = conns2[0]
+    if padW:
+        idle2, conns2 = idle2[:, :W], conns2[:W]
+    return a, warm, idle2, conns2

@@ -3,7 +3,6 @@
 tests live in test_kernels_properties.py so this module runs even where
 hypothesis isn't installed."""
 
-import functools
 import runpy
 from pathlib import Path
 
@@ -12,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.jax_sched import JIQState, sched_many_fused
+from repro.core.jax_sched import JIQState, sched_many, sched_many_fused
 from repro.kernels import ops, ref, sched_step
 
 jax.config.update("jax_enable_x64", False)
@@ -135,6 +134,22 @@ def test_ssd_scan_state_carry_equals_two_halves():
 
 
 # ------------------------------------------------- fused mixed-event dispatch
+def _scan(kinds, funcs, workers, idle, conns):
+    """The burst through ``sched_many``'s event-by-event scan, in the fused
+    call's output order: ``(assign, warm, idle', conns')``."""
+    events = jnp.stack([kinds, funcs, workers], axis=1)
+    state, (ws, warm) = sched_many(JIQState(idle, conns), events)
+    return ws, warm, state.idle, state.conns
+
+
+def _fused(kinds, funcs, workers, idle, conns):
+    """The burst through ``sched_many_fused`` in interpret mode, one launch
+    of the form the table's shape picks, in :func:`_scan`'s order."""
+    events = jnp.stack([kinds, funcs, workers], axis=1)
+    state, (ws, warm) = sched_many_fused(JIQState(idle, conns), events, interpret=True)
+    return ws, warm, state.idle, state.conns
+
+
 @pytest.mark.parametrize(
     "R,F,W,arrivals_only",
     [
@@ -147,7 +162,10 @@ def test_ssd_scan_state_carry_equals_two_halves():
 )
 def test_sched_events_sweep(R, F, W, arrivals_only):
     """Fused (ARRIVAL|FINISH|EVICT) kernel == the jax_sched scan oracle, and
-    on ARRIVAL-only bursts == ``ref.sched_step_ref`` as well."""
+    on ARRIVAL-only bursts == ``ref.sched_step_ref`` as well.  The VMEM
+    form pads W to whole 128-lane rows with +INF connections, in interpret
+    mode as on the chip."""
+    assert sched_step.table_form("test", (F, W), interpret=True) == "vmem"
     rng = np.random.default_rng(R * 1000 + W)
     kinds = np.zeros(R, np.int32) if arrivals_only else rng.integers(0, 3, R)
     funcs = rng.integers(0, F, R)
@@ -155,13 +173,13 @@ def test_sched_events_sweep(R, F, W, arrivals_only):
     idle = rng.integers(0, 3, (F, W))
     conns = rng.integers(0, 5, W)
     args = [jnp.asarray(a, jnp.int32) for a in (kinds, funcs, workers, idle, conns)]
-    a, warm, i2, c2 = ops.sched_events(*args, interpret=True)
-    ar, wr, ir, cr = ref.sched_events_ref(*args)
+    a, warm, i2, c2 = _fused(*args)
+    ar, wr, ir, cr = _scan(*args)
     assert jnp.all(a == ar) and jnp.all(warm == wr)
     assert jnp.all(i2 == ir) and jnp.all(c2 == cr)
     if arrivals_only:
         ar, wr, ir, cr = ref.sched_step_ref(args[1], args[3], args[4])
-        assert jnp.all(a == ar) and jnp.all(warm == wr.astype(jnp.int32))
+        assert jnp.all(a == ar) and jnp.all(warm == wr)
         assert jnp.all(i2 == ir) and jnp.all(c2 == cr)
 
 
@@ -175,10 +193,10 @@ def test_sched_events_hbm_sweep(R, F, W, monkeypatch):
     an event needs brought in per event) == the jax_sched scan oracle and
     the benchmark's plain reference, on mixed streams of all four kinds (3
     is a no-op), with W off the 8-row tiles and F off the 128-lane tiles.
-    A VMEM cap just below the VMEM form's need makes ``ops.sched_events``
+    A VMEM cap just below the VMEM form's need makes ``sched_many_fused``
     take it (interpret mode holds the HBM form to no cap)."""
-    monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
-    assert ops.check_sched_events("test", (F, W), interpret=True) == "hbm"
+    monkeypatch.setattr(sched_step, "VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
+    assert sched_step.table_form("test", (F, W), interpret=True) == "hbm"
     rng = np.random.default_rng(R * 1000 + F * 10 + W)
     kinds = rng.integers(0, 4, R)
     funcs = rng.integers(0, F, R)
@@ -186,10 +204,9 @@ def test_sched_events_hbm_sweep(R, F, W, monkeypatch):
     idle = rng.integers(0, 3, (F, W))
     conns = rng.integers(0, 5, W)
     args = [jnp.asarray(a, jnp.int32) for a in (kinds, funcs, workers, idle, conns)]
-    run = functools.partial(ops.sched_events, interpret=True)
-    assert "_sched_events_hbm" in str(jax.make_jaxpr(run)(*args))
-    got = [np.asarray(x) for x in run(*args)]
-    want = [np.asarray(x) for x in ref.sched_events_ref(*args)]
+    assert "_sched_events_hbm" in str(jax.make_jaxpr(_fused)(*args))
+    got = [np.asarray(x) for x in _fused(*args)]
+    want = [np.asarray(x) for x in _scan(*args)]
     plain = brute_force(idle, conns, np.stack([kinds, funcs, workers], 1))
     for g, w, b in zip(got, want, plain):
         assert np.array_equal(g, w) and np.array_equal(g, np.asarray(b, g.dtype))
@@ -200,29 +217,61 @@ def test_sched_events_native_guards(monkeypatch):
     the backend.  A table wider than the VMEM form's cap takes the HBM
     form, and a width whose rows alone exceed the cap raises naming the
     width instead of running another path."""
-    shapes = [jax.ShapeDtypeStruct(s, jnp.int32) for s in [(8,), (8,), (8,)]]
+    events = jnp.zeros((8, 3), jnp.int32)
     with pytest.raises(RuntimeError, match="'cpu'"):
-        ops.sched_events(*shapes, jnp.zeros((4, 8), jnp.int32),
-                         jnp.zeros((8,), jnp.int32))
+        sched_many_fused(JIQState(jnp.zeros((4, 8), jnp.int32), jnp.zeros((8,), jnp.int32)),
+                         events)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     W = 5000
     F = next(f for f in range(8, 10**5, 8)
-             if sched_step.vmem_bytes(f, W) > ops.SCHED_VMEM_CAP_BYTES)
-    assert ops.check_sched_events("t", (F - 8, W), interpret=False) == "vmem"
-    assert ops.check_sched_events("t", (F, W), interpret=False) == "hbm"
-    native = functools.partial(ops.sched_events, interpret=False)
-    jaxpr = str(jax.make_jaxpr(native)(*shapes, jax.ShapeDtypeStruct((F, W), jnp.int32),
-                                       jax.ShapeDtypeStruct((W,), jnp.int32)))
+             if sched_step.vmem_bytes(f, W) > sched_step.VMEM_CAP_BYTES)
+    assert sched_step.table_form("t", (F - 8, W), interpret=False) == "vmem"
+    assert sched_step.table_form("t", (F, W), interpret=False) == "hbm"
+    native = lambda idle, conns, ev: sched_many_fused(JIQState(idle, conns), ev)  # noqa: E731
+    jaxpr = str(jax.make_jaxpr(native)(jax.ShapeDtypeStruct((F, W), jnp.int32),
+                                       jax.ShapeDtypeStruct((W,), jnp.int32), events))
     assert "_sched_events_hbm" in jaxpr
     # the published 8,192 x 5,000 cluster is past the VMEM form and inside the HBM form
-    assert ops.check_sched_events("t", (8192, 5000), interpret=False) == "hbm"
+    assert sched_step.table_form("t", (8192, 5000), interpret=False) == "hbm"
     wide = next(w for w in range(W, 10**7, 128)
-                if sched_step.hbm_vmem_bytes(w) > ops.SCHED_VMEM_CAP_BYTES)
+                if sched_step.hbm_vmem_bytes(w) > sched_step.VMEM_CAP_BYTES)
     with pytest.raises(ValueError, match=f"{wide} workers"):
-        ops.sched_events(*shapes, jax.ShapeDtypeStruct((F, wide), jnp.int32),
-                         jax.ShapeDtypeStruct((wide,), jnp.int32))
+        sched_step.table_form("t", (F, wide), interpret=False)
     # the whole-stream wrapper checks the same before its one program
     state = JIQState(jax.ShapeDtypeStruct((F, wide), jnp.int32),
                      jax.ShapeDtypeStruct((wide,), jnp.int32))
     with pytest.raises(ValueError, match=f"sched_many_fused: {F} functions x {wide} workers"):
-        sched_many_fused(state, jnp.zeros((8, 3), jnp.int32))
+        sched_many_fused(state, events)
+
+
+_FORBIDDEN = ("repro.models", "repro.sharding", "repro.kernels.ops", "repro.kernels.ref")
+
+
+@pytest.mark.parametrize("code", [
+    "import repro.kernels.sched_step",
+    "import repro.core.jax_sched",
+    "import numpy as np\n"
+    "from repro.core.jax_sched import init_state, sched_many_fused\n"
+    "ev = np.array([[0, 1, -1], [1, 1, 2], [2, 0, 1]], np.int32)\n"
+    "sched_many_fused(init_state(3, 5), ev, interpret=True)[1][0].block_until_ready()",
+], ids=["sched_step", "jax_sched", "fused-call"])
+def test_dispatch_path_loads_no_model_code(code):
+    """The dispatch path (``core.jax_sched`` -> ``kernels.sched_step``)
+    loads none of the LLM stack: no model, sharding, ``ops`` or ``ref``
+    module is imported by the kernel, its wrapper or a call through it."""
+    import os
+    import subprocess
+    import sys
+
+    import repro
+
+    src = os.path.dirname(os.path.dirname(repro.__file__))
+    probe = code + (
+        "\nimport sys\n"
+        f"print(sorted(m for m in sys.modules if m.startswith({_FORBIDDEN!r})))"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", probe], capture_output=True, text=True, timeout=300,
+        check=True, env={**os.environ, "PYTHONPATH": src, "JAX_PLATFORMS": "cpu"},
+    )
+    assert out.stdout.strip().splitlines()[-1] == "[]"

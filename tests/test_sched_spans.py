@@ -21,8 +21,9 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import jax_sched
 from repro.core.jax_sched import ARRIVAL, EVICT, FINISH, JIQState, sched_many_fused
-from repro.kernels import ops, sched_step
+from repro.kernels import sched_step
 from repro.utils.compile_cache import compile_count
 
 CHIP_BENCH = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
@@ -70,8 +71,7 @@ def _traced(tmp_path, fn):
 
 def test_one_call_leaves_one_fused_span_with_its_stages_inside(tmp_path):
     state, ev = _state(5, 11), _events(2500, 5, 11, seed=1)
-    _, spans = _traced(tmp_path, lambda: sched_many_fused(
-        state, ev, chunk=1024, interpret=True))
+    _, spans = _traced(tmp_path, lambda: sched_many_fused(state, ev, interpret=True))
     fused = [s for s in spans if s[0] == "sched.fused"]
     assert len(fused) == 1
     _, lo, hi, stats = fused[0]
@@ -82,30 +82,32 @@ def test_one_call_leaves_one_fused_span_with_its_stages_inside(tmp_path):
     assert all(lo <= s[1] <= s[2] <= hi for s in spans)
 
 
-def test_a_stream_length_compiles_one_program_once():
+def test_a_stream_length_compiles_one_program_once(monkeypatch):
     """A first call at a length (and table shape) no other test uses
     compiles the call's one program, kernel and all; the same length again
     compiles nothing, whatever the events."""
+    monkeypatch.setattr(jax_sched, "CHUNK", 32)
     count = compile_count()
     state = jax.block_until_ready(_state(7, 13))
     made = []
     for seed in (4, 5, 6):
         before = count.compiles
         jax.block_until_ready(sched_many_fused(
-            state, _events(77, 7, 13, seed), chunk=32, interpret=True))
+            state, _events(77, 7, 13, seed), interpret=True))
         made.append(count.compiles - before)
     assert made == [1, 0, 0]
 
 
-def test_compiles_are_counted_in_the_call_that_makes_them(tmp_path):
+def test_compiles_are_counted_in_the_call_that_makes_them(tmp_path, monkeypatch):
     """A first call at a table shape no other test uses compiles; the same
     call again compiles nothing."""
+    monkeypatch.setattr(jax_sched, "CHUNK", 256)
     state, ev = _state(3, 17), _events(300, 3, 17, seed=2)
 
     def twice():
-        first = sched_many_fused(state, ev, chunk=256, interpret=True)
+        first = sched_many_fused(state, ev, interpret=True)
         jax.block_until_ready(first)
-        return sched_many_fused(state, ev, chunk=256, interpret=True)
+        return sched_many_fused(state, ev, interpret=True)
 
     _, spans = _traced(tmp_path, twice)
     first, again = [s[3] for s in spans if s[0] == "sched.fused"]
@@ -116,21 +118,22 @@ def test_compiles_are_counted_in_the_call_that_makes_them(tmp_path):
 
 @pytest.mark.parametrize("n,chunk", [(192, 64), (64, 64), (150, 64), (20, 64)],
                          ids=["whole-chunks", "one-chunk", "ragged-tail", "tail-only"])
-def test_chunks_counts_the_launches_and_nothing_is_padded(tmp_path, n, chunk):
+def test_chunks_counts_the_launches_and_nothing_is_padded(tmp_path, monkeypatch, n, chunk):
     """Each launch runs exactly the events it is given: ``chunks`` is
     ``ceil(n / chunk)`` (``n / chunk`` where it divides) and ``padded`` is
     0, with or without a ragged tail."""
+    monkeypatch.setattr(jax_sched, "CHUNK", chunk)
     state, ev = _state(6, 9), _events(n, 6, 9, seed=11)
-    _, spans = _traced(tmp_path, lambda: sched_many_fused(
-        state, ev, chunk=chunk, interpret=True))
+    _, spans = _traced(tmp_path, lambda: sched_many_fused(state, ev, interpret=True))
     (stats,) = [s[3] for s in spans if s[0] == "sched.fused"]
     assert {k: stats[k] for k in ("chunks", "events", "padded")} == {
         "chunks": -(-n // chunk), "events": n, "padded": 0}
 
 
-def test_answers_and_state_are_the_same_with_a_trace_active(tmp_path):
+def test_answers_and_state_are_the_same_with_a_trace_active(tmp_path, monkeypatch):
+    monkeypatch.setattr(jax_sched, "CHUNK", 512)
     state, ev = _state(4, 9), _events(700, 4, 9, seed=3)
-    call = lambda: sched_many_fused(state, ev, chunk=512, interpret=True)  # noqa: E731
+    call = lambda: sched_many_fused(state, ev, interpret=True)  # noqa: E731
     plain = jax.device_get(call())
     traced, spans = _traced(tmp_path, call)
     assert [s[0] for s in spans].count("sched.fused") == 1
@@ -151,7 +154,7 @@ def test_the_compile_count_counts_a_fresh_jit_compile():
 def _in_hbm(monkeypatch, n_funcs, n_workers):
     """A VMEM cap just below the VMEM form's need, so a small table takes
     the HBM form (interpret mode holds that form to no cap)."""
-    monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(n_funcs, n_workers) - 1)
+    monkeypatch.setattr(sched_step, "VMEM_CAP_BYTES", sched_step.vmem_bytes(n_funcs, n_workers) - 1)
 
 
 @pytest.mark.parametrize("table", ["vmem", "hbm"])
@@ -160,6 +163,7 @@ def test_fused_span_names_the_table_form_and_its_row_loads(tmp_path, monkeypatch
     ARRIVAL, FINISH and EVICT events of a host burst on the HBM form (no-op
     kind 3 loads nothing) and is 0 on the VMEM form.  A device burst leaves
     ``row_loads`` out rather than fetch it."""
+    monkeypatch.setattr(jax_sched, "CHUNK", 64)
     if table == "hbm":
         _in_hbm(monkeypatch, 24, 130)
     state = _state(24, 130)
@@ -169,9 +173,9 @@ def test_fused_span_names_the_table_form_and_its_row_loads(tmp_path, monkeypatch
     assert 0 < loads < len(ev)
 
     def calls():
-        host = sched_many_fused(state, ev, chunk=64, interpret=True)
+        host = sched_many_fused(state, ev, interpret=True)
         jax.block_until_ready(host)
-        return sched_many_fused(state, jnp.asarray(ev), chunk=64, interpret=True)
+        return sched_many_fused(state, jnp.asarray(ev), interpret=True)
 
     _, spans = _traced(tmp_path, calls)
     host, device = [s[3] for s in spans if s[0] == "sched.fused"]
@@ -188,12 +192,13 @@ def test_row_loads_are_the_rows_the_kernel_loads(monkeypatch):
     a FINISH's or EVICT's tile in and out."""
     from jax._src.pallas.mosaic.interpret import interpret_pallas_call as interpreter
 
+    monkeypatch.setattr(jax_sched, "CHUNK", 256)
     _in_hbm(monkeypatch, 40, 200)
     state = _state(40, 200)
 
     def dmas(ev):
         pltpu.reset_tpu_interpret_mode_state()
-        jax.block_until_ready(sched_many_fused(state, ev, chunk=256, interpret=True))
+        jax.block_until_ready(sched_many_fused(state, ev, interpret=True))
         return interpreter.dma_id_counter.value
 
     noop = np.zeros((200, 3), np.int32)
@@ -205,9 +210,10 @@ def test_row_loads_are_the_rows_the_kernel_loads(monkeypatch):
 
 
 def test_hbm_answers_and_state_are_the_same_with_a_trace_active(tmp_path, monkeypatch):
+    monkeypatch.setattr(jax_sched, "CHUNK", 128)
     _in_hbm(monkeypatch, 24, 130)
     state, ev = _state(24, 130), _events(300, 24, 130, seed=9)
-    call = lambda: sched_many_fused(state, ev, chunk=128, interpret=True)  # noqa: E731
+    call = lambda: sched_many_fused(state, ev, interpret=True)  # noqa: E731
     plain = jax.device_get(call())
     traced, spans = _traced(tmp_path, call)
     assert [s[0] for s in spans].count("sched.fused") == 1
@@ -233,16 +239,17 @@ def test_the_benchmark_reads_ns_per_row_load_from_the_spans(tmp_path, monkeypatc
                 "_sched_events_hbm.1 custom-call") else (0.0, 0))
         return types.SimpleNamespace(trace=trace)
 
+    monkeypatch.setattr(jax_sched, "CHUNK", 128)
     ev = _events(100, 24, 130, seed=10)
     _, spans = _traced(tmp_path / "vmem", lambda: sched_many_fused(
-        _state(24, 130), ev, chunk=128, interpret=True))
+        _state(24, 130), ev, interpret=True))
     whole = (spans[0][1], spans[-1][2])
     assert reader.read(ctx(whole), tmp_path / "vmem") is None
     _in_hbm(monkeypatch, 24, 130)
 
     def two_calls():
-        first = sched_many_fused(_state(24, 130), ev, chunk=128, interpret=True)
-        return sched_many_fused(first[0], ev[:40], chunk=128, interpret=True)
+        first = sched_many_fused(_state(24, 130), ev, interpret=True)
+        return sched_many_fused(first[0], ev[:40], interpret=True)
 
     _, spans = _traced(tmp_path / "hbm", two_calls)
     fused = [s for s in spans if s[0] == "sched.fused"]

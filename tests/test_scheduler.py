@@ -125,12 +125,14 @@ def test_jax_sched_evict():
 
 @pytest.mark.parametrize("as_array", [np.asarray, jnp.asarray], ids=["numpy", "jnp"])
 @pytest.mark.parametrize("n", [1, 63, 64, 65, 160])
-def test_sched_many_fused_matches_scan(n, as_array):
+def test_sched_many_fused_matches_scan(n, as_array, monkeypatch):
     """Chunked fused dispatch (interpret mode) == event-by-event scan, at
-    stream lengths on both sides of the 64-event chunk's boundaries, from
-    a host or a device array."""
-    from repro.core import sched_many_fused
+    stream lengths on both sides of a 64-event chunk's boundaries, from
+    a host or a device array.  W = 9 runs the VMEM form's lane padding and
+    its +INF connection mask."""
+    from repro.core import jax_sched, sched_many_fused
 
+    monkeypatch.setattr(jax_sched, "CHUNK", 64)
     rng = np.random.default_rng(5)
     state = init_state(6, 9)
     events = []
@@ -139,7 +141,7 @@ def test_sched_many_fused_matches_scan(n, as_array):
         events.append((k, int(rng.integers(0, 6)), -1 if k == ARRIVAL else int(rng.integers(0, 9))))
     ev = np.array(events, np.int32)
     s1, (ws1, warm1) = sched_many(state, jnp.asarray(ev))
-    s2, (ws2, warm2) = sched_many_fused(state, as_array(ev), chunk=64, interpret=True)
+    s2, (ws2, warm2) = sched_many_fused(state, as_array(ev), interpret=True)
     assert ws2.shape == (n,) and ws2.dtype == jnp.int32 and warm2.dtype == bool
     assert jnp.all(ws1 == ws2) and jnp.all(warm1 == warm2)
     assert jnp.all(s1.idle == s2.idle) and jnp.all(s1.conns == s2.conns)
@@ -160,14 +162,15 @@ brute_force = runpy.run_path(
 def test_sched_many_fused_hbm_matches_scan(n, F, W, monkeypatch):
     """The HBM form through ``sched_many_fused`` (interpret mode, a VMEM
     cap just below the VMEM form's need) == the event-by-event scan and the plain
-    reference, bitwise, on mixed streams of all four kinds, across the
+    reference, bitwise, on mixed streams of all four kinds, across a
     64-event chunk's boundaries (several chunks: the first copies the
     table, the rest update it in place) and with the state carried into a
     second call."""
-    from repro.core import JIQState, sched_many_fused
-    from repro.kernels import ops, sched_step
+    from repro.core import JIQState, jax_sched, sched_many_fused
+    from repro.kernels import sched_step
 
-    monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
+    monkeypatch.setattr(jax_sched, "CHUNK", 64)
+    monkeypatch.setattr(sched_step, "VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
     rng = np.random.default_rng(n * 100 + F)
     kinds = rng.integers(0, 4, 2 * n)
     ev = np.stack([kinds, rng.integers(0, F, 2 * n),
@@ -175,8 +178,8 @@ def test_sched_many_fused_hbm_matches_scan(n, F, W, monkeypatch):
     ev = ev.astype(np.int32)
     idle, conns = rng.integers(0, 3, (F, W)), rng.integers(0, 5, W)
     state = JIQState(jnp.asarray(idle, jnp.int32), jnp.asarray(conns, jnp.int32))
-    mid, (ws_a, warm_a) = sched_many_fused(state, ev[:n], chunk=64, interpret=True)
-    end, (ws_b, warm_b) = sched_many_fused(mid, ev[n:], chunk=64, interpret=True)
+    mid, (ws_a, warm_a) = sched_many_fused(state, ev[:n], interpret=True)
+    end, (ws_b, warm_b) = sched_many_fused(mid, ev[n:], interpret=True)
     fused = (np.concatenate([ws_a, ws_b]), np.concatenate([warm_a, warm_b]),
              np.asarray(end.idle), np.asarray(end.conns))
     s1, (ws1, warm1) = sched_many(state, jnp.asarray(ev))
@@ -191,107 +194,20 @@ def test_sched_many_fused_empty_stream_changes_nothing(table, monkeypatch):
     """No events make no kernel launch, on either form: the state comes
     back bit for bit and the answers are empty, int32 and bool."""
     from repro.core import JIQState, sched_many_fused
-    from repro.kernels import ops, sched_step
+    from repro.kernels import sched_step
 
     F, W = 24, 130
     if table == "hbm":
-        monkeypatch.setattr(ops, "SCHED_VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
-    assert ops.check_sched_events("test", (F, W), interpret=True) == table
+        monkeypatch.setattr(sched_step, "VMEM_CAP_BYTES", sched_step.vmem_bytes(F, W) - 1)
+    assert sched_step.table_form("test", (F, W), interpret=True) == table
     rng = np.random.default_rng(12)
     idle, conns = rng.integers(0, 3, (F, W)), rng.integers(0, 5, W)
     state = JIQState(jnp.asarray(idle, jnp.int32), jnp.asarray(conns, jnp.int32))
-    end, (ws, warm) = sched_many_fused(state, np.zeros((0, 3), np.int32), chunk=64,
-                                       interpret=True)
+    end, (ws, warm) = sched_many_fused(state, np.zeros((0, 3), np.int32), interpret=True)
     assert ws.shape == warm.shape == (0,)
     assert ws.dtype == jnp.int32 and warm.dtype == bool
     assert end.idle.dtype == end.conns.dtype == jnp.int32
     assert np.array_equal(end.idle, idle) and np.array_equal(end.conns, conns)
-
-
-def _mixed_events(rng, n, n_funcs=6, n_workers=9):
-    events = []
-    for _ in range(n):
-        k = int(rng.integers(0, 3))
-        events.append(
-            (k, int(rng.integers(0, n_funcs)),
-             -1 if k == ARRIVAL else int(rng.integers(0, n_workers)))
-        )
-    return jnp.array(events, jnp.int32)
-
-
-def test_sched_many_adaptive_matches_scan_across_chunk_switches():
-    """Burst-adaptive dispatch == event-by-event scan, bitwise, while the
-    detector actually switches chunk sizes mid-stream (the density samples
-    drive it from single-event stepping into fused chunks and back)."""
-    from repro.core import BurstDetector, sched_many_adaptive
-
-    det = BurstDetector(alpha=1.0, thresholds=((100.0, 64),), base_chunk=1)
-    ev = _mixed_events(np.random.default_rng(7), 300)
-    # windows 0,3 step one event at a time; windows 1,2 fuse with chunk=64
-    densities = [0.0, 500.0, 500.0, 0.0]
-    s1, (ws1, warm1) = sched_many(init_state(6, 9), ev)
-    s2, (ws2, warm2) = sched_many_adaptive(
-        init_state(6, 9), ev, det, densities=densities, segment=80,
-        interpret=True,
-    )
-    assert det.chunk == 1  # the quiet tail pulled the EWMA back down
-    assert jnp.all(ws1 == ws2) and jnp.all(warm1 == warm2)
-    assert jnp.all(s1.idle == s2.idle) and jnp.all(s1.conns == s2.conns)
-
-
-def test_sched_many_adaptive_default_density_and_edges():
-    """Without explicit samples the window's own event count drives the
-    detector; ragged tails, empty streams and the PRNG-key fallback all
-    reduce to the scan path's results."""
-    from repro.core import BurstDetector, sched_many_adaptive
-
-    ev = _mixed_events(np.random.default_rng(11), 130)
-    det = BurstDetector(alpha=1.0, thresholds=((64.0, 32),), base_chunk=1)
-    s1, (ws1, warm1) = sched_many(init_state(6, 9), ev)
-    s2, (ws2, warm2) = sched_many_adaptive(
-        init_state(6, 9), ev, det, segment=64, interpret=True
-    )
-    assert jnp.all(ws1 == ws2) and jnp.all(warm1 == warm2)
-    assert jnp.all(s1.conns == s2.conns)
-    # empty stream: no windows, empty outputs, untouched state
-    det2 = BurstDetector()
-    s3, (ws3, warm3) = sched_many_adaptive(
-        init_state(2, 2), jnp.zeros((0, 3), jnp.int32), det2
-    )
-    assert ws3.shape == (0,) and warm3.shape == (0,)
-    assert int(s3.idle.sum()) == 0 and det2.ewma == 0.0
-    # randomized tie-breaks route through the scan path unchanged
-    key = jax.random.key(3)
-    sa, (wa, _) = sched_many(init_state(6, 9), ev, key=key)
-    sb, (wb, _) = sched_many_adaptive(init_state(6, 9), ev, det, key=key)
-    assert jnp.all(wa == wb) and jnp.all(sa.conns == sb.conns)
-    # density samples must cover every window
-    import pytest
-
-    with pytest.raises(ValueError):
-        sched_many_adaptive(init_state(6, 9), ev, det, densities=[1.0], segment=64)
-
-
-def test_burst_detector_thresholds_and_hysteresis():
-    from repro.core import BurstDetector
-
-    det = BurstDetector(
-        alpha=0.5, thresholds=((1000.0, 1024), (100.0, 128)), base_chunk=1
-    )
-    assert det.observe(2000.0) == 1024  # first sample primes the EWMA
-    assert det.observe(0.0) == 1024  # one quiet window: smoothed to 1000
-    assert det.observe(0.0) == 128  # decays through the lower band (500)
-    assert det.observe(0.0) == 128  # 250 still above 100
-    assert det.observe(0.0) == 128  # 125 still above 100
-    assert det.observe(0.0) == 1  # 62.5: below every threshold, base chunk
-    import pytest
-
-    with pytest.raises(ValueError):
-        BurstDetector(alpha=0.0)
-    with pytest.raises(ValueError):
-        BurstDetector(thresholds=((10.0, 16), (20.0, 32)))  # not descending
-    with pytest.raises(ValueError):
-        BurstDetector(base_chunk=0)
 
 
 def test_least_connections_tracker_matches_ref_live(monkeypatch):

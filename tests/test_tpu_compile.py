@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core import jax_sched
-from repro.kernels import ops
+from repro.kernels import sched_step
 from repro.models import build_model, unzip
 
 
@@ -58,14 +58,16 @@ def test_sched_events_compiles_for_v5e(one_chip, n_workers, monkeypatch):
     """The fused dispatch kernel at 40 functions x 1,024-event chunks, at the
     1,600-worker anchor and the 100k-worker cluster (which needs the raised
     scoped-VMEM limit)."""
-    # ops.sched_events refuses a non-TPU default backend; here the target
+    # the form's choice refuses a non-TPU default backend; here the target
     # is the described chip, not the backend this process runs on
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    R, F = 1024, 40
+    R, F = jax_sched.CHUNK, 40
+    table = sched_step.table_form("test", (F, n_workers), interpret=False)
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
     compiled = (
-        jax.jit(functools.partial(ops.sched_events, interpret=False))
-        .lower(i32((R,)), i32((R,)), i32((R,)), i32((F, n_workers)), i32((n_workers,)))
+        sched_step.launch
+        .lower(i32((R,)), i32((R,)), i32((R,)), i32((F, n_workers)), i32((n_workers,)),
+               interpret=False, table=table)
         .compile()
     )
     text = compiled.as_text()
@@ -84,7 +86,7 @@ def test_sched_many_fused_call_compiles_for_v5e(one_chip, n_events):
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
     compiled = (
         jax_sched._fused_call
-        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=1024, interpret=False,
+        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=jax_sched.CHUNK, interpret=False,
                table="vmem")
         .compile()
     )
@@ -114,9 +116,7 @@ def test_sched_events_hbm_compiles_for_v5e(one_chip):
     x 5,000 workers, the table in its default layout: the kernel's
     worker-major view of it is that layout, and the ragged edge (5,000 is no
     multiple of 128) is taken as it is."""
-    from repro.kernels import sched_step
-
-    R, F, W = 1024, 8192, 5000
+    R, F, W = jax_sched.CHUNK, 8192, 5000
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
     kernel = jax.jit(functools.partial(sched_step.sched_events_hbm, interpret=False))
     compiled = kernel.lower(i32((R,)), i32((R,)), i32((R,)), i32((F, W)), i32((1, W))).compile()
@@ -136,12 +136,13 @@ def test_sched_many_fused_hbm_call_compiles_for_v5e(one_chip, n_events):
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
     compiled = (
         jax_sched._fused_call
-        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=1024, interpret=False,
+        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=jax_sched.CHUNK, interpret=False,
                table="hbm")
         .compile()
     )
     _no_table_copy(compiled, F, W)
-    assert len(re.findall(r"%_sched_events_hbm(\.\d+)? = ", compiled.as_text())) == -(-n_events // 1024)
+    launches = re.findall(r"%_sched_events_hbm(\.\d+)? = ", compiled.as_text())
+    assert len(launches) == -(-n_events // jax_sched.CHUNK)
     default = jax.jit(lambda x: x + 1).lower(i32((F, W))).compile()
     assert compiled.input_formats[0][1].layout == default.input_formats[0][0].layout
     assert compiled.output_formats[0].layout == default.input_formats[0][0].layout
@@ -169,7 +170,7 @@ def test_sched_many_fused_launches_are_sized_to_the_burst(one_chip, table, n_eve
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
     compiled = (
         jax_sched._fused_call
-        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=1024, interpret=False,
+        .lower(i32((n_events, 3)), i32((F, W)), i32((W,)), chunk=jax_sched.CHUNK, interpret=False,
                table=table)
         .compile()
     )

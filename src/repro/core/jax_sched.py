@@ -156,8 +156,10 @@ def sched_many_fused(
 
     The table's shape picks the kernel's form
     (``kernels/sched_step.table_form``): the whole table in VMEM where it
-    fits, else the table in HBM with the part of its function's row that
-    an event needs brought in per event (``sched_step.sched_events_hbm``).
+    fits, else the table in HBM with the column blocks that a launch's
+    first ARRIVALs bring in kept in VMEM, and the part of its function's
+    row that any other event needs brought in per event
+    (``sched_step.sched_events_hbm``).
     The table goes in and comes out in its default layout on either form.
     The kernel runs natively, which needs the TPU: on any other backend
     this raises unless ``interpret=True`` runs the kernel body in Pallas's
@@ -175,7 +177,9 @@ def sched_many_fused(
     ``row_loads`` (loads of function rows the kernel makes from HBM,
     counted from the events by the kernel's rule,
     ``sched_step.hbm_row_loads``; 0 on the VMEM form; left out for a device
-    array, which it would have to fetch); and inside it, once each,
+    array, which it would have to fetch) and, beside it on the HBM form,
+    ``resident`` (the events the kernel serves from a column block it
+    keeps in VMEM, by the same rule); and inside it, once each,
     ``sched.stage_in`` (the host's checks of the backend and the table's
     VMEM need), ``sched.launch`` (the dispatch of the one program, which
     also moves host events to the device: on the TPU host that costs less
@@ -199,7 +203,12 @@ def sched_many_fused(
         meta = dict(chunks=full + (tail > 0), events=n, padded=full * chunk + tail - n,
                     compiles=count.compiles - compiles, table=table)
         if isinstance(events, np.ndarray):
-            meta["row_loads"] = 0 if table == "vmem" else _ss.hbm_row_loads(events[:, 0])
+            if table == "vmem":
+                meta["row_loads"] = 0
+            else:
+                slots = _ss.hbm_slots(*state.idle.shape)
+                meta["row_loads"], meta["resident"] = _ss.hbm_row_loads(
+                    events[:, 0], events[:, 1], slots, chunk)
         call.set_metadata(**meta)
     return out
 

@@ -34,11 +34,18 @@ them), so there the view costs no copy, and the table crosses the
 program's boundary in its default layout (JAX's persistent compile cache
 does not keep a non-default one).
 The kernel copies the input table to its output with one HBM-to-HBM DMA.
-An ARRIVAL then brings the ``(W, 128)`` column block that holds its
-function's row into VMEM, transposes it to rows, runs the VMEM kernel's
-row logic on the row and writes back the ``(8, 128)`` tile that holds the
-one count it may change; a FINISH or EVICT brings in and writes back that
-tile alone; a no-op loads nothing.  The TPU compiler slices an HBM table
+An ARRIVAL then needs its function's row, which lies in the ``(W, 128)``
+column block of the ``128`` functions around it.  A block comes into VMEM
+once, transposed to rows, into one of the launch's resident slots
+(:func:`hbm_slots` of them, the first distinct blocks that ARRIVALs bring
+in; nothing is ever evicted), or, once those are taken, into a spill slot
+that the next such ARRIVAL overwrites.  An event on a resident block, an
+ARRIVAL or a FINISH or EVICT after the block's first ARRIVAL, reads and
+updates its row in the slot and loads nothing.  Every event writes
+through the ``(8, 128)`` tile that holds the one count it may change (built
+from the slot, or for a FINISH or EVICT on a block with no slot, brought in
+on its own), so HBM is exact after each event and nothing is written back
+at the end; a no-op loads nothing.  The TPU compiler slices an HBM table
 only in whole tiles, so a block spans the rows up to the next multiple of
 8 (all rows of a table of fewer than 8 workers) and 128 lanes; rows past W
 are tile padding, masked with +INF connections and written back as they
@@ -78,23 +85,59 @@ def vmem_bytes(n_funcs: int, n_workers: int) -> int:
     return 4 * lanes * (2 * (sublanes + 1) + 24) + 2**20
 
 
-def hbm_vmem_bytes(n_workers: int) -> int:
-    """Scoped VMEM :func:`sched_events_hbm` asks for at ``W`` workers,
-    whatever the number of functions: the ``(W, 128)`` column block, its
-    transpose and as much again for the transposition's temporaries, the
-    connection row in, out and resident, the same 24 rows of temporaries
-    as :func:`vmem_bytes`, one ``(8, 128)`` tile, and 1 MiB."""
-    return 4 * _lanes(n_workers) * (3 * 128 + 3 + 24) + 4 * _TILE_ROWS * 128 + 2**20
+def hbm_vmem_bytes(n_workers: int, slots: int = 1) -> int:
+    """Scoped VMEM :func:`sched_events_hbm` asks for at ``W`` workers with
+    ``slots`` resident column blocks (:func:`hbm_slots`), whatever the
+    number of functions: the ``(W, 128)`` staging block, ``slots + 1``
+    transposed ``(128, W)`` blocks (the last is the spill slot), the
+    connection row in, out and resident, the same 24 rows of temporaries as
+    :func:`vmem_bytes`, one ``(8, 128)`` tile, and 1 MiB (compiled for a
+    v5e, the kernel's temporaries take 13-20 rows at 5,000-70,664 workers).
+    At the default one slot this is the least the kernel runs with, which
+    :func:`table_form` holds to the cap."""
+    return 4 * _lanes(n_workers) * ((slots + 2) * 128 + 3 + 24) + 4 * _TILE_ROWS * 128 + 2**20
 
 
-def hbm_row_loads(kinds) -> int:
-    """Loads from HBM of (part of) a function's row that
-    :func:`sched_events_hbm` makes for a host array of event kinds: one per
-    ARRIVAL (the column block holding the whole row), FINISH or EVICT (the
-    tile holding its one count), kinds 0-2; any other kind, a no-op, loads
-    none."""
+def hbm_slots(n_funcs: int, n_workers: int) -> int:
+    """The column blocks :func:`sched_events_hbm` keeps resident through a
+    launch at ``(F, W)``: as many as keep :func:`hbm_vmem_bytes` within half
+    of :data:`VMEM_CAP_BYTES`, at most the table's ``ceil(F / 128)`` blocks
+    and at least 1.  Half, because a skewed mix puts most events on a few
+    blocks: at 8,192 x 5,000 this gives 19 slots (~54 MiB), which serve
+    86.8% of the events of the chip benchmark's ``k8s5000w-batch256``
+    traffic (one seed's 300 calls replayed on the host) against 86.9% with
+    all 64 blocks resident, so the rest of the cap would buy next to
+    nothing."""
+    slot = 4 * 128 * _lanes(n_workers)
+    fit = (VMEM_CAP_BYTES // 2 - hbm_vmem_bytes(n_workers, 0)) // slot
+    return int(max(1, min(-(-n_funcs // 128), fit)))
+
+
+def hbm_row_loads(kinds, funcs, slots: int, chunk: int) -> tuple[int, int]:
+    """What :func:`sched_events_hbm` loads from HBM for host arrays of event
+    kinds and functions run in launches of ``chunk`` events with ``slots``
+    resident blocks: ``(row_loads, resident)``.  In each launch the first
+    ``slots`` distinct column blocks (``func // 128``) that ARRIVALs bring
+    in stay resident.  An ARRIVAL, FINISH or EVICT (kinds 0-2) on such a
+    block after its first ARRIVAL is served from VMEM (``resident``); any
+    other one makes a row load (an ARRIVAL its block, a FINISH or EVICT the
+    tile holding its count); any other kind, a no-op, makes neither.
+    Vectorised, with no loop over events: the call's span counts it."""
     kinds = np.asarray(kinds)
-    return int(np.count_nonzero((kinds >= 0) & (kinds <= 2)))
+    event = np.arange(len(kinds))
+    real = (kinds >= 0) & (kinds <= 2)
+    block = np.where(real, funcs, 0) // 128
+    n_blocks = int(block.max(initial=0)) + 1
+    key = event // chunk * n_blocks + block  # (launch, block)
+    arrivals = event[kinds == 0]
+    first = np.full((-(-len(kinds) // chunk), n_blocks), len(kinds))
+    np.minimum.at(first.reshape(-1), key[arrivals], arrivals)  # each block's first ARRIVAL
+    if slots < n_blocks:  # the first ``slots`` blocks in each launch stay
+        last = np.partition(first, slots - 1, axis=1)[:, slots - 1:slots]
+        first[first > last] = len(kinds)
+    resident = real & (event > first.reshape(-1)[key])
+    n = int(np.count_nonzero(resident))
+    return int(np.count_nonzero(real)) - n, n
 
 
 def _first_argmin(scores, lane):
@@ -166,7 +209,8 @@ def _column_rows(n_workers: int) -> int:
 
 def _sched_events_hbm_kernel(
     n_workers, copy, kinds_ref, funcs_ref, workers_ref, table_ref, conns_ref,
-    assign_ref, warm_ref, table_out, conns_out, cols, rows, tile, conns, sem,
+    assign_ref, warm_ref, table_out, conns_out, cols, rows, tile, conns,
+    slot_of, used, sem,
 ):
     if copy:
         whole = pltpu.make_async_copy(table_ref, table_out, sem)
@@ -174,12 +218,22 @@ def _sched_events_hbm_kernel(
     n_rows = _column_rows(n_workers)
     n_tile = tile.shape[0]
     lanes = conns.shape[1]
+    spill = rows.shape[0] - 1  # the slot past the resident ones
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
     real = lane < n_workers  # rows past W are tile padding
+    sub8 = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0)
+    fn8 = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
     sub = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
     fn = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
     conns[...] = jnp.full((1, lanes), _INF, jnp.int32)
     conns[:, pl.ds(0, n_workers)] = conns_ref[...]
+    used[0] = 0
+
+    def no_slot(b, _):
+        slot_of[b] = -1
+        return 0
+
+    jax.lax.fori_loop(0, slot_of.shape[0], no_slot, 0)
     if copy:
         whole.wait()
 
@@ -197,30 +251,55 @@ def _sched_events_hbm_kernel(
             base = pl.multiple_of((w // n_tile) * n_tile, n_tile)
         return base, table_out.at[pl.ds(base, n_tile), pl.ds(fb, 128)]
 
+    def update_through(s, r, w, fb, update):
+        """Apply ``update(counts, at)`` to the ``(8, 128)`` window of slot
+        ``s`` that holds row ``r``'s count of worker ``w`` (``at`` marks that
+        count; a worker past W matches nothing), then write the tile of HBM
+        that holds it from the slot: the 128 workers around it, transposed
+        back through the staging block.  An event changes one count, so no
+        whole row is rewritten."""
+        w_in = jnp.clip(w, 0, n_workers - 1)
+        rb = pl.multiple_of((r // 8) * 8, 8)
+        lb = pl.multiple_of((w_in // 128) * 128, 128)
+        window = rows.at[s, pl.ds(rb, 8), pl.ds(lb, 128)]
+        window[...] = update(window[...], (sub8 + rb == r) & (fn8 + lb == w))
+        base, hbm = tile_of(w_in, fb)
+        cols[pl.ds(0, 128), :] = rows[s, :, pl.ds(lb, 128)].T
+        dma(cols.at[pl.ds(base - lb, n_tile), :], hbm)
+
     def body(i, _):
         k = kinds_ref[i]
         f = funcs_ref[i]
         w_ev = workers_ref[i]
-        fb = pl.multiple_of((f // 128) * 128, 128)
-        at_f = fn == f - fb
+        b = f // 128
+        fb = pl.multiple_of(b * 128, 128)
         assign_ref[i] = -1
         warm_ref[i] = 0
 
         @pl.when(k == 0)
         def _arrival():
-            dma(table_out.at[pl.ds(0, n_rows), pl.ds(fb, 128)], cols.at[pl.ds(0, n_rows), :])
-            rows[...] = cols[...].T
-            raw = rows[pl.ds(f - fb, 1), :]
+            @pl.when(slot_of[b] < 0)
+            def _load():
+                # the block into the next free slot while one is left, else
+                # into the spill slot
+                u = used[0]
+                dma(table_out.at[pl.ds(0, n_rows), pl.ds(fb, 128)], cols.at[pl.ds(0, n_rows), :])
+                rows[jnp.minimum(u, spill)] = cols[...].T
+
+                @pl.when(u < spill)
+                def _keep():
+                    slot_of[b] = u
+                    used[0] = u + 1
+
+            s = slot_of[b]
+            s = jnp.where(s < 0, spill, s)
             _, c, _, w_assign, dec = _event(
-                k, w_ev, lane, lambda: jnp.where(real, raw, 0), lambda: conns[...],
+                k, w_ev, lane, lambda: jnp.where(real, rows[s, pl.ds(f - fb, 1), :], 0),
+                lambda: conns[...],
             )
             conns[...] = c
             # the ARRIVAL changes at most the count at (w_assign, f)
-            base, hbm = tile_of(w_assign, fb)
-            block = cols[pl.ds(base, n_tile), :]
-            at = (sub + base == w_assign) & at_f
-            cols[pl.ds(base, n_tile), :] = block - jnp.where(at, dec, 0)
-            dma(cols.at[pl.ds(base, n_tile), :], hbm)
+            update_through(s, f - fb, w_assign, fb, lambda x, at: x - jnp.where(at, dec, 0))
             assign_ref[i] = w_assign
             warm_ref[i] = dec
 
@@ -230,12 +309,24 @@ def _sched_events_hbm_kernel(
             # removal (the VMEM kernel's rules, on the one count they touch)
             is_fin = (k == 1).astype(jnp.int32)
             is_evt = (k == 2).astype(jnp.int32)
-            base, hbm = tile_of(jnp.clip(w_ev, 0, n_workers - 1), fb)
-            dma(hbm, tile)
-            at = (sub + base == w_ev) & at_f  # a worker past W matches nothing
-            block = tile[...] + jnp.where(at, is_fin, 0)
-            tile[...] = block - jnp.where(at & (block > 0), is_evt, 0)
-            dma(tile, hbm)
+            s = slot_of[b]
+
+            def notice(x, at):
+                x = x + jnp.where(at, is_fin, 0)
+                return x - jnp.where(at & (x > 0), is_evt, 0)
+
+            @pl.when(s >= 0)
+            def _resident():
+                update_through(s, f - fb, w_ev, fb, notice)
+
+            @pl.when(s < 0)
+            def _load():
+                base, hbm = tile_of(jnp.clip(w_ev, 0, n_workers - 1), fb)
+                dma(hbm, tile)
+                at = (sub + base == w_ev) & (fn == f - fb)  # a worker past W matches nothing
+                tile[...] = notice(tile[...], at)
+                dma(tile, hbm)
+
             c = conns[...]
             conns[...] = jnp.where(lane == w_ev, jnp.maximum(c - is_fin, 0), c)
 
@@ -300,6 +391,7 @@ def sched_events_hbm(
     conns: jax.Array,    # (1, W) int32
     in_place: bool = False,
     interpret: bool = False,
+    slots: int | None = None,
 ):
     """:func:`sched_events` with the idle table left in HBM: the same
     arguments, results and semantics, for any ``(F, W)`` whose
@@ -311,13 +403,17 @@ def sched_events_hbm(
     writes its whole output table: one HBM-to-HBM DMA of the input, then
     the tiles the events change.  ``in_place=True`` makes the output alias
     the input and copies nothing, for a table the caller may overwrite (one
-    its own program made).  Each ARRIVAL, FINISH and EVICT loads from HBM
-    once (:func:`hbm_row_loads`).  ``interpret=True`` runs it in the TPU
-    interpreter (its DMAs included) on the table padded as HBM holds it
+    its own program made).  ``slots`` column blocks stay resident through
+    the launch (default :func:`hbm_slots` of the shape; tests set fewer);
+    :func:`hbm_row_loads` counts what the events load from HBM and what
+    they find resident.  ``interpret=True`` runs it in the TPU interpreter
+    (its DMAs included) on the table padded as HBM holds it
     (:func:`_as_in_hbm`).
     """
     R = kinds.shape[0]
     F, W = idle.shape
+    if slots is None:
+        slots = hbm_slots(F, W)
     table = idle.T
     if interpret:
         table = _as_in_hbm(table)
@@ -336,13 +432,15 @@ def sched_events_hbm(
         ],
         scratch_shapes=[
             pltpu.VMEM((_lanes(W), 128), jnp.int32),
-            pltpu.VMEM((128, _lanes(W)), jnp.int32),
+            pltpu.VMEM((slots + 1, 128, _lanes(W)), jnp.int32),
             pltpu.VMEM((min(W, _TILE_ROWS), 128), jnp.int32),
             pltpu.VMEM((1, _lanes(W)), jnp.int32),
+            pltpu.SMEM((-(-F // 128),), jnp.int32),  # a block's slot, or -1
+            pltpu.SMEM((1,), jnp.int32),  # slots taken
             pltpu.SemaphoreType.DMA(()),
         ],
         input_output_aliases={3: 2} if in_place else {},
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=hbm_vmem_bytes(W)),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=hbm_vmem_bytes(W, slots)),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="_sched_events_hbm",
     )(kinds, funcs, workers, table, conns)

@@ -212,11 +212,67 @@ def test_sched_events_hbm_sweep(R, F, W, monkeypatch):
         assert np.array_equal(g, w) and np.array_equal(g, np.asarray(b, g.dtype))
 
 
+def _resident_stream(F, W, seed):
+    """A stream over the several 128-function column blocks of ``F``
+    functions that takes every path of the HBM kernel's resident slots: a
+    FINISH and an EVICT on blocks before their first ARRIVAL, the first
+    blocks in and repeat ARRIVALs on them, an EVICT of a zero count and
+    notices once they are resident, then ARRIVALs on every other block
+    (through the spill slot once the slots are taken) with a notice after
+    each block's, and a mixed tail of all four kinds.  Returns ``(kinds,
+    funcs, workers, idle, conns)``."""
+    rng = np.random.default_rng(seed)
+    n_blocks = -(-F // 128)
+    fn = lambda b: min(b * 128 + int(rng.integers(128)), F - 1)  # noqa: E731
+    wk = lambda: int(rng.integers(W))  # noqa: E731
+    idle = rng.integers(0, 3, (F, W))
+    conns = rng.integers(0, 5, W)
+    ev = []
+    for b in (1, n_blocks - 1):
+        ev += [(1, fn(b), wk()), (2, fn(b), wk())]
+    ev += [(0, fn(b), -1) for b in (0, 1, 0, 0, 1)]
+    f0, w0 = fn(0), wk()
+    idle[f0, w0] = 0
+    ev.append((2, f0, w0))  # nothing before it enqueues (f0, w0)
+    for b in (0, 1):
+        ev += [(1, fn(b), wk()), (2, fn(b), wk())]
+    for b in range(2, n_blocks):
+        ev += [(0, fn(b), -1), (0, fn(b), -1), (1, fn(b), wk())]
+    for _ in range(60):
+        k = int(rng.integers(4))
+        ev.append((k, int(rng.integers(F)), -1 if k == 0 else wk()))
+    kinds, funcs, workers = np.array(ev, np.int32).T
+    return kinds, funcs, workers, idle, conns
+
+
+@pytest.mark.parametrize("W", [13, 130, 200])
+@pytest.mark.parametrize("slots", [1, 2])
+def test_sched_events_hbm_resident_slots(W, slots):
+    """The HBM form with fewer resident slots than the table has column
+    blocks (700 functions: 6 blocks) == the jax_sched scan oracle and the
+    benchmark's plain reference, answers and state, on a stream that runs
+    resident hits, write-through notices, a zero-count EVICT in a resident
+    block and ARRIVALs through the spill slot."""
+    F = 700
+    kinds, funcs, workers, idle, conns = _resident_stream(F, W, seed=W * 10 + slots)
+    loads, resident = sched_step.hbm_row_loads(kinds, funcs, slots, len(kinds))
+    assert resident > 0 and loads > 0
+    args = [jnp.asarray(a, jnp.int32) for a in (kinds, funcs, workers, idle, conns)]
+    a, warm, i2, c2 = sched_step.sched_events_hbm(
+        *args[:4], args[4][None, :], slots=slots, interpret=True)
+    got = [np.asarray(x) for x in (a, warm.astype(bool), i2, c2[0])]
+    want = [np.asarray(x) for x in _scan(*args)]
+    plain = brute_force(idle, conns, np.stack([kinds, funcs, workers], 1))
+    for g, w, b in zip(got, want, plain):
+        assert np.array_equal(g, w) and np.array_equal(g, np.asarray(b, g.dtype))
+
+
 def test_sched_events_native_guards(monkeypatch):
     """The native kernel never runs elsewhere: off the TPU it raises naming
     the backend.  A table wider than the VMEM form's cap takes the HBM
-    form, and a width whose rows alone exceed the cap raises naming the
-    width instead of running another path."""
+    form, and a width at which even one resident column block (beside the
+    staging and spill blocks) exceeds the cap raises naming the width
+    instead of running another path."""
     events = jnp.zeros((8, 3), jnp.int32)
     with pytest.raises(RuntimeError, match="'cpu'"):
         sched_many_fused(JIQState(jnp.zeros((4, 8), jnp.int32), jnp.zeros((8,), jnp.int32)),
@@ -234,7 +290,7 @@ def test_sched_events_native_guards(monkeypatch):
     # the published 8,192 x 5,000 cluster is past the VMEM form and inside the HBM form
     assert sched_step.table_form("t", (8192, 5000), interpret=False) == "hbm"
     wide = next(w for w in range(W, 10**7, 128)
-                if sched_step.hbm_vmem_bytes(w) > sched_step.VMEM_CAP_BYTES)
+                if sched_step.hbm_vmem_bytes(w, slots=1) > sched_step.VMEM_CAP_BYTES)
     with pytest.raises(ValueError, match=f"{wide} workers"):
         sched_step.table_form("t", (F, wide), interpret=False)
     # the whole-stream wrapper checks the same before its one program

@@ -7,7 +7,9 @@ same clock as the device's operations: ``sched.fused`` per call, with its
 it one ``sched.stage_in``, one ``sched.launch`` (the call's one jitted
 program, whatever its number of chunks) and one ``sched.stage_out``.
 ``sched.fused`` also names the kernel's form (``table``) and, for a host
-burst, the loads of function rows the kernel makes from HBM (``row_loads``).
+burst, the loads of function rows the kernel makes from HBM (``row_loads``)
+and, on the HBM form, the events it serves from a column block it keeps in
+VMEM (``resident``).
 These tests trace calls on the CPU, with the kernel in Pallas's
 interpreter, and read the trace back with ``jax.profiler.ProfileData``.
 """
@@ -157,12 +159,34 @@ def _in_hbm(monkeypatch, n_funcs, n_workers):
     monkeypatch.setattr(sched_step, "VMEM_CAP_BYTES", sched_step.vmem_bytes(n_funcs, n_workers) - 1)
 
 
+def _walk(kinds, funcs, slots, chunk):
+    """The HBM kernel's rule of loads, event by event: in each launch of
+    ``chunk`` events the first ``slots`` distinct column blocks that
+    ARRIVALs bring in stay resident; a real event on one after its first
+    ARRIVAL is ``resident``, any other real event a row load.  Returns
+    ``(row_loads, resident)``."""
+    loads = resident = 0
+    for start in range(0, len(kinds), chunk):
+        kept = set()
+        for k, f in zip(kinds[start:start + chunk], funcs[start:start + chunk]):
+            if k not in (ARRIVAL, FINISH, EVICT):
+                continue
+            if f // 128 in kept:
+                resident += 1
+                continue
+            loads += 1
+            if k == ARRIVAL and len(kept) < slots:
+                kept.add(f // 128)
+    return loads, resident
+
+
 @pytest.mark.parametrize("table", ["vmem", "hbm"])
 def test_fused_span_names_the_table_form_and_its_row_loads(tmp_path, monkeypatch, table):
-    """``table`` names the form the shape chose; ``row_loads`` counts the
-    ARRIVAL, FINISH and EVICT events of a host burst on the HBM form (no-op
-    kind 3 loads nothing) and is 0 on the VMEM form.  A device burst leaves
-    ``row_loads`` out rather than fetch it."""
+    """``table`` names the form the shape chose.  On the HBM form a host
+    burst's ``row_loads`` and ``resident`` split its ARRIVAL, FINISH and
+    EVICT events (no-op kind 3 counts in neither) by the kernel's rule; the
+    VMEM form reads ``row_loads`` 0 and no ``resident``.  A device burst
+    leaves both out rather than fetch it."""
     monkeypatch.setattr(jax_sched, "CHUNK", 64)
     if table == "hbm":
         _in_hbm(monkeypatch, 24, 130)
@@ -180,21 +204,29 @@ def test_fused_span_names_the_table_form_and_its_row_loads(tmp_path, monkeypatch
     _, spans = _traced(tmp_path, calls)
     host, device = [s[3] for s in spans if s[0] == "sched.fused"]
     assert host["table"] == device["table"] == table
-    assert int(host["row_loads"]) == (loads if table == "hbm" else 0)
-    assert "row_loads" not in device
+    if table == "hbm":
+        got = (int(host["row_loads"]), int(host["resident"]))
+        assert got == _walk(ev[:, 0], ev[:, 1], sched_step.hbm_slots(24, 130), 64)
+        assert sum(got) == loads and got[1] > 0
+    else:
+        assert int(host["row_loads"]) == 0 and "resident" not in host
+    assert "row_loads" not in device and "resident" not in device
 
 
 def test_row_loads_are_the_rows_the_kernel_loads(monkeypatch):
-    """On a seeded one-chunk stream the span's ``row_loads`` is what the HBM
-    kernel brings in: the TPU interpreter numbers every DMA a kernel starts,
-    and the kernel starts one whole-table copy plus, per row load, a load
-    and a store: an ARRIVAL's column block in and the tile it changes out,
-    a FINISH's or EVICT's tile in and out."""
+    """On a seeded one-chunk stream over three column blocks with one
+    resident slot, the span's counts are what the HBM kernel moves: the TPU
+    interpreter numbers every DMA a kernel starts, and the kernel starts
+    one whole-table copy, then per row load a load and a store (an
+    ARRIVAL's column block in and the tile it changes out, a FINISH's or
+    EVICT's tile in and out) and per resident event one store (the tile it
+    changes, built in VMEM)."""
     from jax._src.pallas.mosaic.interpret import interpret_pallas_call as interpreter
 
     monkeypatch.setattr(jax_sched, "CHUNK", 256)
-    _in_hbm(monkeypatch, 40, 200)
-    state = _state(40, 200)
+    _in_hbm(monkeypatch, 300, 200)
+    state = _state(300, 200)
+    assert sched_step.hbm_slots(300, 200) == 1
 
     def dmas(ev):
         pltpu.reset_tpu_interpret_mode_state()
@@ -203,10 +235,45 @@ def test_row_loads_are_the_rows_the_kernel_loads(monkeypatch):
 
     noop = np.zeros((200, 3), np.int32)
     noop[:, 0] = 3
-    ev = _events(200, 40, 200, seed=8, kinds=(ARRIVAL, FINISH, EVICT, 3),
+    ev = _events(200, 300, 200, seed=8, kinds=(ARRIVAL, FINISH, EVICT, 3),
                  p=(0.4, 0.3, 0.1, 0.2))
-    assert dmas(ev) - dmas(noop) == 2 * sched_step.hbm_row_loads(ev[:, 0])
-    assert sched_step.hbm_row_loads(ev[:, 0]) == int((ev[:, 0] != 3).sum())
+    loads, resident = sched_step.hbm_row_loads(ev[:, 0], ev[:, 1], 1, 256)
+    assert loads > 0 and resident > 0
+    assert dmas(ev) - dmas(noop) == 2 * loads + resident
+    assert loads + resident == int((ev[:, 0] != 3).sum())
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_load_count_is_the_rule_walked_event_by_event(seed):
+    """``sched_step.hbm_row_loads`` (vectorised, as the timed call needs)
+    equals a plain walk of the kernel's rule on seeded streams of several
+    launches, with fewer slots than blocks, as many, and more, and with
+    skewed functions so blocks repeat."""
+    rng = np.random.default_rng(seed)
+    n, n_funcs = int(rng.integers(1, 900)), int(rng.integers(1, 1100))
+    ev = _events(n, n_funcs, 7, seed, kinds=(ARRIVAL, FINISH, EVICT, 3), p=(0.4, 0.3, 0.1, 0.2))
+    ev[:, 1] = np.minimum(ev[:, 1], rng.geometric(0.004, n) - 1)
+    n_blocks = -(-n_funcs // 128)
+    for slots in sorted({1, max(1, n_blocks // 2), n_blocks, n_blocks + 2}):
+        for chunk in (int(rng.integers(1, 64)), 128, 1024):
+            assert sched_step.hbm_row_loads(ev[:, 0], ev[:, 1], slots, chunk) == _walk(
+                ev[:, 0], ev[:, 1], slots, chunk)
+
+
+def test_a_call_of_several_launches_counts_each_launchs_slots(tmp_path, monkeypatch):
+    """The span of a call of several launches (``CHUNK`` cut short) counts
+    each launch's resident blocks from scratch, as the kernel keeps them
+    per launch: its ``row_loads`` and ``resident`` equal the walked rule."""
+    monkeypatch.setattr(jax_sched, "CHUNK", 48)
+    _in_hbm(monkeypatch, 400, 13)
+    ev = _events(170, 400, 13, seed=12, kinds=(ARRIVAL, FINISH, EVICT, 3),
+                 p=(0.4, 0.3, 0.1, 0.2))
+    _, spans = _traced(tmp_path, lambda: sched_many_fused(_state(400, 13), ev, interpret=True))
+    (stats,) = [s[3] for s in spans if s[0] == "sched.fused"]
+    assert int(stats["chunks"]) == 4
+    got = (int(stats["row_loads"]), int(stats["resident"]))
+    assert got == _walk(ev[:, 0], ev[:, 1], sched_step.hbm_slots(400, 13), 48)
+    assert got[1] > 0
 
 
 def test_hbm_answers_and_state_are_the_same_with_a_trace_active(tmp_path, monkeypatch):
@@ -253,5 +320,46 @@ def test_the_benchmark_reads_ns_per_row_load_from_the_spans(tmp_path, monkeypatc
 
     _, spans = _traced(tmp_path / "hbm", two_calls)
     fused = [s for s in spans if s[0] == "sched.fused"]
-    assert reader.read(ctx((fused[0][1], fused[1][2])), tmp_path / "hbm") == 6.0e6 / 140
-    assert reader.read(ctx((fused[1][1], fused[1][2])), tmp_path / "hbm") == 6.0e6 / 40
+    slots = sched_step.hbm_slots(24, 130)
+    first, second = (_walk(e[:, 0], e[:, 1], slots, 128)[0] for e in (ev, ev[:40]))
+    assert reader.read(ctx((fused[0][1], fused[1][2])), tmp_path / "hbm") == 6.0e6 / (
+        first + second)
+    assert reader.read(ctx((fused[1][1], fused[1][2])), tmp_path / "hbm") == 6.0e6 / second
+
+
+def test_the_benchmark_reads_resident_share_from_the_spans(tmp_path, monkeypatch):
+    """The chip benchmark's ``sched_kernel.resident_share`` is the window's
+    summed ``resident`` over its summed ``resident + row_loads``, in
+    percent, and reads nothing where the calls keep no block resident (a
+    table in VMEM) or where there is no trace."""
+    import importlib.util
+
+    monkeypatch.syspath_prepend(str(CHIP_BENCH))
+    spec = importlib.util.spec_from_file_location(
+        "resident_share", CHIP_BENCH / "metrics" / "sched_kernel.resident_share.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    ctx = lambda window: types.SimpleNamespace(  # noqa: E731
+        trace=types.SimpleNamespace(window=window))
+
+    monkeypatch.setattr(jax_sched, "CHUNK", 128)
+    ev = _events(100, 300, 130, seed=13)
+    assert reader.read(ctx((0, 1)), tmp_path / "none") is None
+    _, spans = _traced(tmp_path / "vmem", lambda: sched_many_fused(
+        _state(300, 130), ev, interpret=True))
+    assert reader.read(ctx((spans[0][1], spans[-1][2])), tmp_path / "vmem") is None
+    _in_hbm(monkeypatch, 300, 130)
+
+    def two_calls():
+        first = sched_many_fused(_state(300, 130), ev, interpret=True)
+        return sched_many_fused(first[0], ev[:40], interpret=True)
+
+    _, spans = _traced(tmp_path / "hbm", two_calls)
+    fused = [s for s in spans if s[0] == "sched.fused"]
+    slots = sched_step.hbm_slots(300, 130)
+    (l1, r1), (l2, r2) = (_walk(e[:, 0], e[:, 1], slots, 128) for e in (ev, ev[:40]))
+    assert 0 < r1 < r1 + l1
+    assert reader.read(ctx((fused[0][1], fused[1][2])), tmp_path / "hbm") == pytest.approx(
+        100.0 * (r1 + r2) / (r1 + l1 + r2 + l2))
+    assert reader.read(ctx((fused[1][1], fused[1][2])), tmp_path / "hbm") == pytest.approx(
+        100.0 * r2 / (r2 + l2))
